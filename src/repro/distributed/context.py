@@ -10,8 +10,6 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Tuple
 
-import jax
-
 
 @dataclasses.dataclass(frozen=True)
 class DistContext:
@@ -38,27 +36,3 @@ class DistContext:
 
 def divisible(n: int, by: int) -> bool:
     return by > 0 and n % by == 0
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs, check_vma=None,
-                     axis_names=None):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes ``jax.shard_map(check_vma=..., axis_names=...)``;
-    older releases only have ``jax.experimental.shard_map.shard_map``
-    with ``check_rep`` and the complementary ``auto`` axis set."""
-    if hasattr(jax, "shard_map"):
-        kw = {}
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    kw = {}
-    if check_vma is not None:
-        kw["check_rep"] = check_vma
-    if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)

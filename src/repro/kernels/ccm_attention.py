@@ -114,14 +114,6 @@ def ccm_flash_attention(q, k, v, q_idx, q_seg, k_idx, k_seg, k_comp, k_valid,
 
     grid = (B, Hq, nq, nk)
     kernel = functools.partial(_kernel, scale=scale, nk=nk)
-    try:
-        cparams = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))
-    except AttributeError:  # older jax
-        cparams = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -143,7 +135,9 @@ def ccm_flash_attention(q, k, v, q_idx, q_seg, k_idx, k_seg, k_comp, k_valid,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        compiler_params=cparams,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
     )(q_idx[None, :], q_seg[None, :], k_idx[None, :], k_seg[None, :],
       k_comp[None, :], k_valid[None, :], q, k, v)

@@ -57,12 +57,6 @@ def cond_lora_matmul(x, w, a, b, gate, scale: float,
     r = a.shape[0]
     nm, nn, nk = M // block_m, N // block_n, K // block_k
     kernel = functools.partial(_kernel, scale=scale, nk=nk)
-    try:
-        cparams = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except AttributeError:
-        cparams = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
     return pl.pallas_call(
         kernel,
         grid=(nm, nn, nk),
@@ -80,6 +74,7 @@ def cond_lora_matmul(x, w, a, b, gate, scale: float,
             pltpu.VMEM((block_m, block_n), jnp.float32),
             pltpu.VMEM((block_m, r), jnp.float32),
         ],
-        compiler_params=cparams,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w, a, b, gate[:, None])

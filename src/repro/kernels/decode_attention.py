@@ -28,11 +28,14 @@ own stacked cache) use the lane-major layout ``(lanes, L, S, H, D)``
 (``lane_major=True``); a layered segment shared across an inner batch
 keeps the model-native layer-major ``(L, B, S, H, D)``.
 
-Layouts are the model's native (B, S, H, D) — segments are consumed where
-they live; no per-step transpose of a large cache.  Block shapes are
-(1, bk, 1, D), i.e. strided row DMA per head; revisit sublane packing if
-a real-TPU profile shows the DMA bound (this container validates via
-interpret).
+KV layouts are the model's native (B, S, Hkv, D) — segments are consumed
+where they live; no per-step transpose of a large cache.  A KV block is
+(1, bk, Hkv, D): every KV head at once, so its trailing dims are whole
+and the block is legal on TPU for any head count, and each KV byte is
+read once per q block.  The grid is (lanes, q blocks, k blocks); one step
+folds all Hq query heads, each against its group's KV head.  Only q is
+transposed, to head-major (B, Hq, Sq, D).  Per-token metadata rides as
+(B, 1, S) so its (1, bk) blocks are legal too.
 
 Mask predicate per (q, k), identical to models.attention.mask_from_info:
   causal AND (same-segment OR key-is-<COMP>) AND key-valid AND pos<length
@@ -74,13 +77,14 @@ def _desc(off: int, S: int, bk: int, quantized: bool, has_info: bool,
     return SegDesc(off, nk, bk, quantized, has_info, layered, lane_major, n)
 
 
-def _kernel(descs, scale, nk_total,
+def _kernel(descs, scale, nk_total, G,
             lens_ref, qidx_ref, qseg_ref, q_ref, *rest):
     n_in = sum(d.n_refs for d in descs)
     o_ref = rest[n_in]
     m_ref, l_ref, acc_ref = rest[n_in + 1:]
+    Hq, bq = q_ref.shape[1], q_ref.shape[2]
     b = pl.program_id(0)
-    ik = pl.program_id(3)
+    ik = pl.program_id(2)
 
     @pl.when(ik == 0)
     def _init():
@@ -114,23 +118,7 @@ def _kernel(descs, scale, nk_total,
         @pl.when(visible)
         def _fold(d=d, k_ref=k_ref, v_ref=v_ref, ks_ref=ks_ref,
                   vs_ref=vs_ref, meta=meta, start=start, seg_len=seg_len):
-            q = q_ref[0, :, 0, :].astype(jnp.float32)        # (bq, D)
-            if d.layered:
-                k, v = k_ref[0, 0, :, 0, :], v_ref[0, 0, :, 0, :]
-            else:
-                k, v = k_ref[0, :, 0, :], v_ref[0, :, 0, :]
-            if d.quantized:   # tile-wise in-kernel dequant
-                ks = ks_ref[0, 0, :, 0] if d.layered else ks_ref[0, :, 0]
-                vs = vs_ref[0, 0, :, 0] if d.layered else vs_ref[0, :, 0]
-                k = k.astype(jnp.float32) * ks[:, None]
-                v = v.astype(jnp.float32) * vs[:, None]
-            else:
-                k = k.astype(jnp.float32)
-                v = v.astype(jnp.float32)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # (bq, bk)
-            pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            pos = start + jax.lax.broadcasted_iota(jnp.int32, (bq, d.bk), 1)
             mask = pos < seg_len
             if d.has_info:
                 kidx, kseg, kcomp, kval = (r[0, :] for r in meta)
@@ -140,21 +128,41 @@ def _kernel(descs, scale, nk_total,
                     & ((kseg[None, :] == qseg[:, None])
                        | (kcomp[None, :] > 0)) \
                     & (kval[None, :] > 0)
-            s = jnp.where(mask, s, NEG_INF)
-            m_prev = m_ref[:, 0]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-            p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
-            alpha = jnp.exp(m_prev - m_new)
-            l_ref[:, 0] = l_ref[:, 0] * alpha + jnp.sum(p, axis=1)
-            acc_ref[...] = acc_ref[...] * alpha[:, None] \
-                + jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-            m_ref[:, 0] = m_new
+            # the block holds every KV head: load each once and fold the
+            # G query heads of its group against it
+            for j in range(Hq // G):
+                lead = (0, 0) if d.layered else (0,)
+                k = k_ref[lead + (slice(None), j, slice(None))]   # (bk, D)
+                v = v_ref[lead + (slice(None), j, slice(None))]
+                k = k.astype(jnp.float32)
+                v = v.astype(jnp.float32)
+                if d.quantized:   # tile-wise in-kernel dequant
+                    sc = lead + (slice(None), slice(j, j + 1))
+                    k = k * ks_ref[sc]
+                    v = v * vs_ref[sc]
+                for h in range(j * G, (j + 1) * G):
+                    q = q_ref[0, h].astype(jnp.float32)       # (bq, D)
+                    s = jax.lax.dot_general(
+                        q, k, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * scale
+                    s = jnp.where(mask, s, NEG_INF)            # (bq, bk)
+                    m_prev = m_ref[h]                          # (bq, 1)
+                    m_new = jnp.maximum(m_prev,
+                                        jnp.max(s, axis=1, keepdims=True))
+                    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+                    alpha = jnp.exp(m_prev - m_new)
+                    l_ref[h] = l_ref[h] * alpha \
+                        + jnp.sum(p, axis=1, keepdims=True)
+                    acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                        p, v, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                    m_ref[h] = m_new
 
     @pl.when(ik == nk_total - 1)
     def _final():
-        l = jnp.maximum(l_ref[:, 0], 1e-37)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        for h in range(Hq):
+            l = jnp.maximum(l_ref[h], 1e-37)
+            o_ref[0, h] = (acc_ref[h] / l).astype(o_ref.dtype)
 
 
 def segmented_flash_attention(q, segs: Sequence[Dict[str, Any]],
@@ -198,11 +206,18 @@ def segmented_flash_attention(q, segs: Sequence[Dict[str, Any]],
             x = jnp.broadcast_to(x, (B,) + x.shape)
         return x
 
+    def rows(x):
+        """(B, S) metadata -> (B, 1, S): blocks (1, width) are legal."""
+        return x[:, None, :]
+
     bq = min(block_q, max(Sq, 8))
-    qp = _pad_axis(q, bq, 1)
-    nq = qp.shape[1] // bq
-    qi = _pad_axis(lanes(jnp.asarray(q_idx, jnp.int32)), bq, 1, fill=-big)
-    qs = _pad_axis(lanes(jnp.asarray(q_seg, jnp.int32)), bq, 1, fill=-3)
+    # head-major q (small: the query block), so a block holds all heads
+    qp = _pad_axis(q.transpose(0, 2, 1, 3), bq, 2)
+    nq = qp.shape[2] // bq
+    qi = rows(_pad_axis(lanes(jnp.asarray(q_idx, jnp.int32)), bq, 1,
+                        fill=-big))
+    qs = rows(_pad_axis(lanes(jnp.asarray(q_seg, jnp.int32)), bq, 1,
+                        fill=-3))
 
     descs: List[SegDesc] = []
     ns = len(segs)
@@ -226,27 +241,29 @@ def segmented_flash_attention(q, segs: Sequence[Dict[str, Any]],
             jnp.zeros((), jnp.int32) if not layered
             else jnp.asarray(s["layer"], jnp.int32), (B,)))
 
-        def im_kv(b, h, iq, ik, lens_ref, d=d, si=si):
+        def im_kv(b, iq, ik, lens_ref, d=d, si=si):
             blk = jnp.clip(ik - d.off, 0, d.nk - 1)
             if d.lane_major:
-                return (b, lens_ref[b, ns + si], blk, h // G, 0)
+                return (b, lens_ref[b, ns + si], blk, 0, 0)
             if d.layered:
-                return (lens_ref[b, ns + si], b, blk, h // G, 0)
-            return (b, blk, h // G, 0)
+                return (lens_ref[b, ns + si], b, blk, 0, 0)
+            return (b, blk, 0, 0)
 
-        def im_sc(b, h, iq, ik, lens_ref, d=d, si=si):
+        def im_sc(b, iq, ik, lens_ref, d=d, si=si):
             blk = jnp.clip(ik - d.off, 0, d.nk - 1)
             if d.lane_major:
-                return (b, lens_ref[b, ns + si], blk, h // G)
+                return (b, lens_ref[b, ns + si], blk, 0)
             if d.layered:
-                return (lens_ref[b, ns + si], b, blk, h // G)
-            return (b, blk, h // G)
+                return (lens_ref[b, ns + si], b, blk, 0)
+            return (b, blk, 0)
 
-        def im_meta(b, h, iq, ik, lens_ref, d=d):
-            return (b, jnp.clip(ik - d.off, 0, d.nk - 1))
+        def im_meta(b, iq, ik, lens_ref, d=d):
+            return (b, 0, jnp.clip(ik - d.off, 0, d.nk - 1))
 
-        kv_block = (1, 1, bk, 1, D) if layered else (1, bk, 1, D)
-        sc_block = (1, 1, bk, 1) if layered else (1, bk, 1)
+        # every KV head per block: the trailing (Hkv, D) dims are whole,
+        # so the block is legal on TPU for any head count
+        kv_block = (1, 1, bk, Hkv, D) if layered else (1, bk, Hkv, D)
+        sc_block = (1, 1, bk, Hkv) if layered else (1, bk, Hkv)
         inputs += [_pad_axis(s["k"], bk, tok_ax),
                    _pad_axis(s["v"], bk, tok_ax)]
         in_specs += [pl.BlockSpec(kv_block, im_kv)] * 2
@@ -259,46 +276,39 @@ def segmented_flash_attention(q, segs: Sequence[Dict[str, Any]],
             if valid is None:
                 valid = jnp.ones((S,), bool)
             inputs += [
-                _pad_axis(lanes(jnp.asarray(s["idx"], jnp.int32)), bk, 1,
-                          fill=big),
-                _pad_axis(lanes(jnp.asarray(s["seg"], jnp.int32)), bk, 1,
-                          fill=-2),
-                _pad_axis(lanes(s["comp"]).astype(jnp.int32), bk, 1),
-                _pad_axis(lanes(valid).astype(jnp.int32), bk, 1)]
-            in_specs += [pl.BlockSpec((1, bk), im_meta)] * 4
+                rows(_pad_axis(lanes(jnp.asarray(s["idx"], jnp.int32)), bk,
+                               1, fill=big)),
+                rows(_pad_axis(lanes(jnp.asarray(s["seg"], jnp.int32)), bk,
+                               1, fill=-2)),
+                rows(_pad_axis(lanes(s["comp"]).astype(jnp.int32), bk, 1)),
+                rows(_pad_axis(lanes(valid).astype(jnp.int32), bk, 1))]
+            in_specs += [pl.BlockSpec((None, 1, bk), im_meta)] * 4
 
     nk_total = off
 
-    def im_q(b, h, iq, ik, lens_ref):
-        return (b, iq, h, 0)
+    def im_q(b, iq, ik, lens_ref):
+        return (b, 0, iq, 0)
 
-    def im_qmeta(b, h, iq, ik, lens_ref):
-        return (b, iq)
+    def im_qmeta(b, iq, ik, lens_ref):
+        return (b, 0, iq)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B, Hq, nq, nk_total),
-        in_specs=[pl.BlockSpec((1, bq), im_qmeta),
-                  pl.BlockSpec((1, bq), im_qmeta),
-                  pl.BlockSpec((1, bq, 1, D), im_q)] + in_specs,
-        out_specs=pl.BlockSpec((1, bq, 1, D), im_q),
-        scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
-                        pltpu.VMEM((bq, 1), jnp.float32),
-                        pltpu.VMEM((bq, D), jnp.float32)])
-    kernel = functools.partial(_kernel, tuple(descs), scale, nk_total)
-    try:
-        cparams = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))
-    except AttributeError:  # older jax
-        cparams = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))
+        grid=(B, nq, nk_total),
+        in_specs=[pl.BlockSpec((None, 1, bq), im_qmeta),
+                  pl.BlockSpec((None, 1, bq), im_qmeta),
+                  pl.BlockSpec((1, Hq, bq, D), im_q)] + in_specs,
+        out_specs=pl.BlockSpec((1, Hq, bq, D), im_q),
+        scratch_shapes=[pltpu.VMEM((Hq, bq, 1), jnp.float32),
+                        pltpu.VMEM((Hq, bq, 1), jnp.float32),
+                        pltpu.VMEM((Hq, bq, D), jnp.float32)])
+    kernel = functools.partial(_kernel, tuple(descs), scale, nk_total, G)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
-        compiler_params=cparams,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.stack(lens + layers, axis=1), qi, qs, qp, *inputs)
-    return out[:, :Sq]
+    return out[:, :, :Sq].transpose(0, 2, 1, 3)

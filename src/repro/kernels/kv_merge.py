@@ -63,24 +63,24 @@ def _cummean_kernel(h_ref, o_ref, acc_ref, *, T: int):
 
 
 def kv_cummean(h, block_cols: int = 512, interpret: bool = True):
-    """h (T, R) -> running means along axis 0."""
+    """h (T, R) -> running means along axis 0.
+
+    Rows ride as (T, 1, R) so each (1, block_cols) block's trailing dims
+    are legal on TPU: 1 is the whole unit dim, and the columns are the
+    whole row or a multiple of 128."""
     T, R = h.shape
-    bc = min(block_cols, R)
+    bc = R if R <= block_cols else block_cols // 128 * 128
     ncol = pl.cdiv(R, bc)
     kernel = functools.partial(_cummean_kernel, T=T)
-    try:
-        cparams = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
-    except AttributeError:
-        cparams = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=(ncol, T),
-        in_specs=[pl.BlockSpec((1, bc), lambda ic, it: (it, ic))],
-        out_specs=pl.BlockSpec((1, bc), lambda ic, it: (it, ic)),
-        out_shape=jax.ShapeDtypeStruct((T, R), h.dtype),
+        in_specs=[pl.BlockSpec((None, 1, bc), lambda ic, it: (it, 0, ic))],
+        out_specs=pl.BlockSpec((None, 1, bc), lambda ic, it: (it, 0, ic)),
+        out_shape=jax.ShapeDtypeStruct((T, 1, R), h.dtype),
         scratch_shapes=[pltpu.VMEM((1, bc), jnp.float32)],
-        compiler_params=cparams,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(h)
+    )(h[:, None, :])
+    return out[:, 0, :]

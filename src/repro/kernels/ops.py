@@ -94,35 +94,38 @@ def cond_lora(x, w, a, b, gate, scale: float, block_m: int = 128,
     return out[:M, :N]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def session_gather(slab, ids, interpret: Optional[bool] = None):
+@functools.partial(jax.jit, static_argnames=("interpret", "sharded"))
+def session_gather(slab, ids, interpret: Optional[bool] = None,
+                   sharded: bool = False):
     """Arena pack: slab (S, ...), ids (B,) int32 -> (B, ...) rows.
 
     TPU -> compiled Pallas DMA gather; elsewhere the pure-jnp ref (unless
     ``interpret=True`` forces the Pallas interpreter for validation).
+    ``sharded``: the slab spans several devices outside `shard_map` (a
+    mesh-placed arena's off-hot-path pack) — XLA's own gather, which the
+    partitioner can split; a Mosaic kernel cannot be partitioned.
     """
-    if interpret is None and not _use_interpret():
-        interpret = False
-    if interpret is None:
+    if sharded or (interpret is None and _use_interpret()):
         return _ref.session_gather_ref(slab, ids)
     S = slab.shape[0]
-    flat = slab.reshape(S, -1)
-    out = _sess.session_gather(flat, ids, interpret=interpret)
+    out = _sess.session_gather(slab.reshape(S, 1, -1), ids,
+                               interpret=bool(interpret))
     return out.reshape((ids.shape[0],) + slab.shape[1:])
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",), donate_argnums=(0,))
-def session_scatter(slab, ids, rows, interpret: Optional[bool] = None):
+@functools.partial(jax.jit, static_argnames=("interpret", "sharded"),
+                   donate_argnums=(0,))
+def session_scatter(slab, ids, rows, interpret: Optional[bool] = None,
+                    sharded: bool = False):
     """Arena unpack: slab (S, ...) with slab[ids] = rows (B, ...), in place
-    (the slab argument is donated on both backends)."""
-    if interpret is None and not _use_interpret():
-        interpret = False
-    if interpret is None:
+    (the slab argument is donated on both backends).  ``sharded`` as in
+    `session_gather`."""
+    if sharded or (interpret is None and _use_interpret()):
         return _ref.session_scatter_ref(slab, ids, rows)
     S = slab.shape[0]
-    out = _sess.session_scatter(slab.reshape(S, -1), ids,
-                                rows.reshape(rows.shape[0], -1),
-                                interpret=interpret)
+    out = _sess.session_scatter(slab.reshape(S, 1, -1), ids,
+                                rows.reshape(rows.shape[0], 1, -1),
+                                interpret=bool(interpret))
     return out.reshape(slab.shape)
 
 

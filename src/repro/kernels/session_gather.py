@@ -1,12 +1,18 @@
 """Session-arena pack/unpack kernels — Pallas TPU.
 
 The serving arena stores per-session state as slabs with a leading slot
-axis (S, R).  Building a scheduler batch is a row gather (pack) and the
+axis.  Building a scheduler batch is a row gather (pack) and the
 post-step writeback is a row scatter (unpack).  Both are pure DMA: the
 scalar-prefetched slot ids drive the BlockSpec index maps, so each grid
-step copies one (1, block_cols) tile HBM->VMEM->HBM with no compute.
+step copies one row tile HBM->VMEM->HBM with no compute.
 
-  session_gather  — rows = slab[ids]          (B, R) out of (S, R)
+Rows are viewed as ``(S, 1, R)``: the slot axis is squeezed out of the
+block, and the block's last two dims ``(1, block_cols)`` equal the
+array's unit middle dim and either equal ``R`` or are a multiple of 128,
+which is what the TPU lowering requires of every block (the (8, 128)
+rule) — for bf16 KV rows, memory rows and ``R == 1`` counters alike.
+
+  session_gather  — rows = slab[ids]          (B, 1, R) out of (S, 1, R)
   session_scatter — slab[ids] = rows, in place via input/output aliasing
                     (donated slab buffer; untouched rows are not copied)
 
@@ -22,28 +28,34 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def _block_cols(R: int, block_cols: int) -> int:
+    """The whole row when it fits one block, else a 128-multiple tile."""
+    return R if R <= block_cols else block_cols // 128 * 128
+
+
 def _copy_kernel(ids_ref, src_ref, dst_ref):
     del ids_ref
     dst_ref[...] = src_ref[...]
 
 
-def session_gather(slab, ids, block_cols: int = 1024,
+def session_gather(slab, ids, block_cols: int = 16384,
                    interpret: bool = True):
-    """slab (S, R), ids (B,) int32 -> (B, R) packed rows."""
-    S, R = slab.shape
+    """slab (S, 1, R), ids (B,) int32 -> (B, 1, R) packed rows."""
+    S, _, R = slab.shape
     B = ids.shape[0]
-    bc = min(block_cols, R)
+    bc = _block_cols(R, block_cols)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, pl.cdiv(R, bc)),
-        in_specs=[pl.BlockSpec((1, bc), lambda b, c, ids_ref:
-                               (ids_ref[b], c))],
-        out_specs=pl.BlockSpec((1, bc), lambda b, c, ids_ref: (b, c)),
+        in_specs=[pl.BlockSpec((None, 1, bc), lambda b, c, ids_ref:
+                               (ids_ref[b], 0, c))],
+        out_specs=pl.BlockSpec((None, 1, bc),
+                               lambda b, c, ids_ref: (b, 0, c)),
     )
     return pl.pallas_call(
         _copy_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, R), slab.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, 1, R), slab.dtype),
         interpret=interpret,
     )(ids.astype(jnp.int32), slab)
 
@@ -53,30 +65,32 @@ def _scatter_kernel(ids_ref, rows_ref, slab_ref, out_ref):
     out_ref[...] = rows_ref[...]
 
 
-def session_scatter(slab, ids, rows, block_cols: int = 1024,
+def session_scatter(slab, ids, rows, block_cols: int = 16384,
                     interpret: bool = True):
-    """slab (S, R), ids (B,), rows (B, R) -> slab with slab[ids] = rows.
+    """slab (S, 1, R), ids (B,), rows (B, 1, R) -> slab with
+    slab[ids] = rows.
 
     The slab operand is aliased to the output, so only the B touched rows
     move; everything else stays in the donated buffer.
     """
-    S, R = slab.shape
+    S, _, R = slab.shape
     B = ids.shape[0]
-    bc = min(block_cols, R)
+    bc = _block_cols(R, block_cols)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, pl.cdiv(R, bc)),
         in_specs=[
-            pl.BlockSpec((1, bc), lambda b, c, ids_ref: (b, c)),
-            pl.BlockSpec((1, bc), lambda b, c, ids_ref: (ids_ref[b], c)),
+            pl.BlockSpec((None, 1, bc), lambda b, c, ids_ref: (b, 0, c)),
+            # the aliased slab is only written: left in HBM, never read
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, bc), lambda b, c, ids_ref:
-                               (ids_ref[b], c)),
+        out_specs=pl.BlockSpec((None, 1, bc), lambda b, c, ids_ref:
+                               (ids_ref[b], 0, c)),
     )
     return pl.pallas_call(
         _scatter_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, R), slab.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, 1, R), slab.dtype),
         input_output_aliases={2: 0},   # slab (after the prefetched ids) -> out
         interpret=interpret,
     )(ids.astype(jnp.int32), rows, slab)

@@ -13,10 +13,8 @@ from repro.distributed.context import DistContext
 
 
 def _mk(shape, axes):
-    if hasattr(jax.sharding, "AxisType"):      # jax >= 0.6
-        auto = (jax.sharding.AxisType.Auto,) * len(axes)
-        return jax.make_mesh(shape, axes, axis_types=auto)
-    return jax.make_mesh(shape, axes)          # older jax: Auto is implied
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -236,7 +236,6 @@ def make_sharded_arena_step(cfg: ModelConfig, op: str, mesh,
 
     One jit per (op, ragged) like the single-shard builder; distinct
     (S, B, token_len) shapes each compile once."""
-    from repro.distributed.context import shard_map_compat
     from repro.kernels import ops as KOPS
     vf = session_vmap(cfg, op, ragged)
 
@@ -261,8 +260,8 @@ def make_sharded_arena_step(cfg: ModelConfig, op: str, mesh,
 
     shard = P("shards")
     out_specs = shard if op == "ingest" else (shard, shard)
-    sharded = shard_map_compat(
-        body, mesh,
+    sharded = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(P(), shard, shard, shard, shard),
         out_specs=out_specs,
         # per-lane counters make leaves device-varying in ways the
@@ -277,9 +276,10 @@ def make_sharded_arena_step(cfg: ModelConfig, op: str, mesh,
     return jax.jit(fn, donate_argnums=(1,))
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "group"),
+@functools.partial(jax.jit, static_argnames=("cfg", "group", "sharded"),
                    donate_argnums=(0,))
-def recompress_arena_slots(mem_slabs, ids, cfg: ModelConfig, group: int):
+def recompress_arena_slots(mem_slabs, ids, cfg: ModelConfig, group: int,
+                           sharded: bool = False):
     """Arena-resident memory recompression: gather the ``ids`` rows of
     the slabs' `MemState` subtree, collapse every ``group`` consecutive
     filled <COMP> groups per lane (`core.memory.recompress_memory`,
@@ -292,18 +292,22 @@ def recompress_arena_slots(mem_slabs, ids, cfg: ModelConfig, group: int):
     or pad lanes gathering the scratch row) are re-selected bit-exactly.
     Module-level jit: `ModelConfig` is hashable, so every engine —
     and every fuzzed simulation trace — shares one compile per
-    (shape, cfg, group)."""
+    (shape, cfg, group).  ``sharded``: the slabs span a mesh (see
+    `kernels.ops.session_gather`)."""
     from repro.kernels import ops as KOPS
-    mem = jax.tree.map(lambda s: KOPS.session_gather(s, ids), mem_slabs)
+    mem = jax.tree.map(
+        lambda s: KOPS.session_gather(s, ids, sharded=sharded), mem_slabs)
     # shrink only when it frees at least one group: ceil(g/r) < g
     do = -(-mem.slots // group) < mem.slots
     new = STR.recompress_memory_lanes(cfg, mem, group, do)
     return jax.tree.map(
-        lambda s, r: KOPS.session_scatter(s, ids, r), mem_slabs, new)
+        lambda s, r: KOPS.session_scatter(s, ids, r, sharded=sharded),
+        mem_slabs, new)
 
 
-@functools.partial(jax.jit, donate_argnums=(0,))
-def cow_clone_slots(slabs, src_ids, dst_ids):
+@functools.partial(jax.jit, static_argnames=("sharded",),
+                   donate_argnums=(0,))
+def cow_clone_slots(slabs, src_ids, dst_ids, sharded: bool = False):
     """Copy-on-write break: clone the ``src_ids`` rows of every slab
     leaf into the freshly-allocated ``dst_ids`` rows — one jitted
     gather/scatter over the donated slabs, batched over all of a shard's
@@ -316,11 +320,14 @@ def cow_clone_slots(slabs, src_ids, dst_ids):
     its reference on the shared slot, and repoints the session — the
     siblings' view of the original row is never touched.  Module-level
     jit like `recompress_arena_slots`: every arena (engines, fuzzed
-    simulation traces) shares one compile per shape."""
+    simulation traces) shares one compile per shape.  ``sharded``: the
+    slabs span a mesh (see `kernels.ops.session_gather`)."""
     from repro.kernels import ops as KOPS
-    rows = jax.tree.map(lambda s: KOPS.session_gather(s, src_ids), slabs)
+    rows = jax.tree.map(
+        lambda s: KOPS.session_gather(s, src_ids, sharded=sharded), slabs)
     return jax.tree.map(
-        lambda s, r: KOPS.session_scatter(s, dst_ids, r), slabs, rows)
+        lambda s, r: KOPS.session_scatter(s, dst_ids, r, sharded=sharded),
+        slabs, rows)
 
 
 def make_null_step(cfg: ModelConfig, op: str, ragged: bool = False

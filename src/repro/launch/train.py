@@ -24,7 +24,7 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.core import masks as M
 from repro.data.synthetic import ShardableIndexIterator, sample_kv_batch
 from repro.distributed import sharding as SH
-from repro.distributed.context import DistContext, shard_map_compat
+from repro.distributed.context import DistContext
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
 from repro.obs import perf_counter
@@ -82,7 +82,7 @@ def make_train_step(cfg: ModelConfig, layout: M.SegmentLayout,
             nb = dist.n_data
             ef_spec = EFState(jax.tree.map(
                 lambda _: P(dist.batch_axes), ef.residual))
-            loss, grads, ef = shard_map_compat(
+            loss, grads, ef = jax.shard_map(
                 shard_grads, mesh=dist.mesh,
                 in_specs=(P(), P(), SH.batch_spec(dist), ef_spec),
                 out_specs=(P(), P(), ef_spec),

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.context import DistContext, shard_map_compat
+from repro.distributed.context import DistContext
 from repro.models.config import ModelConfig
 from repro.models import layers as L
 
@@ -161,7 +161,7 @@ def apply_moe(cfg: ModelConfig, p: Dict, x: jnp.ndarray,
     if cfg.moe_impl == "ep":
         ep = dist.n_model
         body = functools.partial(_moe_ep_shard, cfg, model_axis=ma, ep=ep)
-        y = shard_map_compat(
+        y = jax.shard_map(
             lambda pp, xx: body(pp, xf=xx),
             mesh=dist.mesh,
             in_specs=({"router": P(), "wi": P(ma), "wg": P(ma), "wo": P(ma)},
@@ -171,7 +171,7 @@ def apply_moe(cfg: ModelConfig, p: Dict, x: jnp.ndarray,
         )(p, xf)
     else:
         body = functools.partial(_moe_tp_shard, cfg, model_axis=ma)
-        y = shard_map_compat(
+        y = jax.shard_map(
             lambda pp, xx: body(pp, xx),
             mesh=dist.mesh,
             in_specs=({"router": P(), "wi": P(None, None, ma),

@@ -78,15 +78,20 @@ class ArenaFull(RuntimeError):
 # identity, so per-instance `jax.jit(...)` wrappers would recompile the
 # same gather/scatter for every arena built (one per fuzzed trace in
 # tests/simulation.py, one per engine elsewhere).
-@jax.jit
-def _pack_slabs(slabs, ids):
-    return jax.tree.map(lambda slab: ops.session_gather(slab, ids), slabs)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _scatter_slabs(slabs, ids, state):
+# ``sharded``: the slabs span a mesh (a placed arena) and these run
+# outside `shard_map`, so they take XLA's partitionable gather/scatter.
+@functools.partial(jax.jit, static_argnames=("sharded",))
+def _pack_slabs(slabs, ids, sharded=False):
     return jax.tree.map(
-        lambda slab, rows: ops.session_scatter(slab, ids, rows),
+        lambda slab: ops.session_gather(slab, ids, sharded=sharded), slabs)
+
+
+@functools.partial(jax.jit, static_argnames=("sharded",),
+                   donate_argnums=(0,))
+def _scatter_slabs(slabs, ids, state, sharded=False):
+    return jax.tree.map(
+        lambda slab, rows: ops.session_scatter(slab, ids, rows,
+                                               sharded=sharded),
         slabs, state)
 
 
@@ -321,14 +326,15 @@ class SessionArena:
     def pack(self, slot_ids: Sequence[int]):
         """Gather slots into a batch: leaves (B,) + template shape."""
         ids = jnp.asarray(slot_ids, jnp.int32)
-        return self._pack(self.slabs, ids)
+        return self._pack(self.slabs, ids, sharded=self.placed)
 
     def unpack(self, slot_ids: Sequence[int], state) -> None:
         """Scatter an updated batch back (donates slabs + batch)."""
         self._guard_writes(slot_ids)
         ids = jnp.asarray(slot_ids, jnp.int32)
         self._dirty.update(int(i) for i in slot_ids)
-        self.slabs = self._scatter(self.slabs, ids, state)
+        self.slabs = self._scatter(self.slabs, ids, state,
+                                   sharded=self.placed)
 
     def mark_dirty(self, slot_ids: Sequence[int]) -> None:
         """Record external writes (the engine's fused step updates

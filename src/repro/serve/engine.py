@@ -810,7 +810,7 @@ class ServeEngine:
         arena = mgr.arena
         arena.slabs = arena.slabs._replace(mem=SRV.recompress_arena_slots(
             arena.slabs.mem, jnp.asarray([sess.slot], jnp.int32),
-            cfg=self.cfg, group=group))
+            cfg=self.cfg, group=group, sharded=arena.placed))
         arena.mark_dirty([sess.slot])
         sess.mem_groups = new_groups
         return freed
@@ -1321,7 +1321,8 @@ class ServeEngine:
         0 so totals can be summed; this is the ONE place the clamp
         happens (callers must not re-clamp)."""
         out: Dict[str, int] = {}
-        for (op, _), fn in self._steps.items():
+        steps = list(self._steps.items()) + list(self._sharded_steps.items())
+        for (op, _), fn in steps:
             n = fn._cache_size() if hasattr(fn, "_cache_size") else -1
             prev = out.get(op, 0)
             out[op] = -1 if (n < 0 or prev < 0) else prev + n

@@ -44,6 +44,7 @@ first activation (all state inits are zeros + zero counters).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Callable, Collection, Dict, List, Optional, Sequence
 
@@ -206,8 +207,6 @@ class SessionManager:
         # victim's row would otherwise stay alive on a cache pin alone —
         # evicting the session then frees nothing and activation starves
         self.cache_unpin: Optional[Callable[[int], bool]] = None
-        self._host = jax.devices("cpu")[0]
-        self._device = jax.local_devices()[0]
         self._state_bytes = sum(
             math.prod(leaf.shape) * np.dtype(leaf.dtype).itemsize
             for leaf in jax.tree.leaves(arena.template))
@@ -271,6 +270,12 @@ class SessionManager:
         for s in range(arena.n_shards):
             self._g_shard_bw.labels(shard=str(s))
             self._m_cow.labels(shard=str(s))
+
+    @functools.cached_property
+    def _host(self):
+        """Offload target, looked up at the first offload: an engine that
+        never offloads needs no CPU backend."""
+        return jax.devices("cpu")[0]
 
     def _count_transfer(self, direction: str, n_rows: int, n_sessions: int,
                         seconds: float, measured: bool,
@@ -612,7 +617,8 @@ class SessionManager:
             src_ids = np.asarray(src + [pad] * (n - len(src)), np.int32)
             dst_ids = np.asarray(dst + [pad] * (n - len(dst)), np.int32)
             self.arena.slabs = cow_clone_slots(
-                self.arena.slabs, src_ids, dst_ids)
+                self.arena.slabs, src_ids, dst_ids,
+                sharded=self.arena.placed)
             for sess, new in zip(group, dst):
                 old = sess.slot
                 sess.slot = new
@@ -819,7 +825,9 @@ class SessionManager:
                 # the multi-device slab operand
                 dev = stacked
             else:
-                dev = jax.device_put(stacked, self._device)
+                # the device that holds this (unsharded) arena
+                slab = jax.tree.leaves(self.arena.slabs)[0]
+                dev = jax.device_put(stacked, next(iter(slab.devices())))
             self.arena.unpack(ids, dev)
             # dispatch time only: blocking here to measure the true copy
             # would serialize restore against the batch that triggered it

@@ -387,8 +387,10 @@ def test_decode_vmap_lane_capacity_invariance():
         if cap == 32:
             for i in range(3):
                 lg1, _ = I.decode_step(params, cfg, lanes[i], toks[i:i+1, :1])
+                # 16 float32 ulps at |logit| ~ 3.5: XLA reassociates the
+                # vmapped lane matmuls' sums differently from one lane's
                 np.testing.assert_allclose(outs[0][i], np.asarray(lg1),
-                                           atol=1e-6)
+                                           atol=4e-6)
     np.testing.assert_array_equal(outs[0], outs[1])
 
 

@@ -7,15 +7,7 @@ import subprocess
 import sys
 import textwrap
 
-import jax
-import pytest
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-# jax < 0.6 (no jax.shard_map) runs shard_map through the legacy
-# experimental API; two cases hit version-specific limits there (see
-# ROADMAP "jax-version compat")
-OLD_JAX = not hasattr(jax, "shard_map")
 
 
 def _run(body: str, devices: int = 8, timeout: int = 900):
@@ -67,8 +59,6 @@ def test_pjit_train_step_sharded():
     assert "OK" in out
 
 
-@pytest.mark.xfail(OLD_JAX, reason="legacy shard_map: TP/EP combine "
-                   "exceeds tolerance on jax<0.6", strict=False)
 def test_moe_tp_ep_equivalence():
     out = _run("""
         from repro.models import moe as MOE
@@ -92,9 +82,6 @@ def test_moe_tp_ep_equivalence():
     assert "OK" in out
 
 
-@pytest.mark.xfail(OLD_JAX, reason="legacy shard_map partial-manual "
-                   "reduce crashes XLA (IsManualSubgroup) on jax<0.6",
-                   strict=False)
 def test_grad_compression_distributed():
     out = _run("""
         from repro.optim.grad_compress import EFState

@@ -540,8 +540,10 @@ def test_ragged_stream_equivalence(tiny_cfg, params):
     for t, req in zip(toks, reqs):
         lg, st = ST.stream_step(params2, cfg, st, t[None])
         assert req.result.shape[0] == 3
+        # 16 float32 ulps at |logit| ~ 3.5: XLA reassociates the vmapped
+        # lane matmuls' sums differently from the unbatched step's
         np.testing.assert_allclose(np.asarray(req.result),
-                                   np.asarray(lg[0]), atol=2e-6, rtol=0)
+                                   np.asarray(lg[0]), atol=4e-6, rtol=0)
     assert int(st.mem.slots) > 0                     # evictions compressed
     mgr = eng._mgr["stream"]
     got = mgr.arena.read_slot(mgr.sessions["u"].slot)
