@@ -5,15 +5,18 @@ with seeded random weights through the entry points a user calls:
 ``ServeEngine.create_session`` / ``ingest`` / ``query`` / ``stream`` /
 ``fork_session`` and a prefix-cache hit.  Each served query's logits are
 checked against a direct ``ingest_context`` + ``prefill`` of the same
-session on the same params and device.
+session on the same params and device, and negative controls (a wrong
+history) must fail that check.  First, the arena's Pallas gather/scatter
+is checked bit for bit against XLA's on every arena leaf.
 
-    python chip_smoke.py            # one chip: the serve phase
+    python chip_smoke.py            # one chip: arena kernels, serve phase
     python chip_smoke.py --chips 4  # four chips: session-sharded phase only
 
 The four-chip phase serves the same seeded traffic on a 4-shard engine
 (one arena shard per chip, `shard_map` hot path) and on a 1-shard engine,
-and checks that the results agree, that no session state crossed a
-shard, and that each session's arena row lives on its shard's chip.
+and checks that the results agree (and that another session's result
+does not), that no session state crossed a shard, and that each
+session's arena row lives on its shard's chip.
 
 Earlier lines report compile seconds, per-phase wall seconds (smoke
 timings, not benchmarks), compiled-program counts and peak device
@@ -114,18 +117,68 @@ class Direct:
         return np.asarray(logits[0], np.float32)
 
 
-def compare(name: str, got, want) -> float:
+def distance(name: str, got, want):
+    """(max|got-want|, the bound) after the shape and finiteness checks."""
     got = np.asarray(got, np.float32)
     check(got.shape == want.shape,
           f"{name}: logits shape {got.shape} != {want.shape}")
     check(bool(np.isfinite(got).all()), f"{name}: non-finite logits")
-    err = float(np.abs(got - want).max())
+    diff = np.abs(got - want)
     bound = LOGIT_RTOL * float(np.abs(want).max())
-    log(f"check {name}: max|got-want|={err:.6g} "
-        f"bound={bound:.6g} max|logit|={float(np.abs(want).max()):.6g}")
-    check(err <= bound, f"{name}: engine logits differ from the direct "
-                        f"path by {err} > {bound}")
+    log(f"check {name}: max|got-want|={float(diff.max()):.6g} "
+        f"mean|got-want|={float(diff.mean()):.6g} bound={bound:.6g} "
+        f"max|logit|={float(np.abs(want).max()):.6g}")
+    return float(diff.max()), bound
+
+
+def compare(name: str, got, want) -> float:
+    err, bound = distance(name, got, want)
+    check(err <= bound, f"{name}: engine logits differ from the reference "
+                        f"by {err} > {bound}")
     return err
+
+
+def control(name: str, got, wrong) -> float:
+    """Negative control: logits of a wrong state (another session's
+    rows, or a write that did not land) must fail `compare`."""
+    err, bound = distance(f"control {name}", got, wrong)
+    check(err > bound, f"control {name}: a wrong state is within the "
+                       f"bound ({err} <= {bound}); the check cannot see it")
+    return err
+
+
+def _random(key, shape, dtype):
+    if jnp.issubdtype(dtype, jnp.floating):
+        return jax.random.normal(key, shape, jnp.float32).astype(dtype)
+    info = jnp.iinfo(dtype)
+    return jax.random.randint(key, shape, info.min, info.max, dtype)
+
+
+def kernel_phase(cfg, seed: int = 0) -> int:
+    """The arena's Pallas gather/scatter against XLA's take / .at[].set
+    on every leaf of the online and stream arenas.  Both are pure data
+    movement, so they must agree bit for bit."""
+    from repro.kernels import ops, ref
+    from repro.serve.arena import online_template, stream_template
+    ids = jnp.asarray([3, 0, N_SLOTS, 5], jnp.int32)   # N_SLOTS: scratch
+    key = jax.random.PRNGKey(seed)
+    leaves = (jax.tree.leaves(online_template(cfg, CACHE_LEN))
+              + jax.tree.leaves(stream_template(cfg)))
+    for i, leaf in enumerate(leaves):
+        key, k_slab, k_rows = jax.random.split(key, 3)
+        slab = _random(k_slab, (N_SLOTS + 1,) + leaf.shape, leaf.dtype)
+        rows = _random(k_rows, ids.shape + leaf.shape, leaf.dtype)
+        got = ops.session_gather(slab, ids, interpret=False)
+        check(np.array_equal(np.asarray(got),
+                             np.asarray(ref.session_gather_ref(slab, ids))),
+              f"arena leaf {i} {leaf.shape}: kernel gather != jnp.take")
+        want = np.asarray(ref.session_scatter_ref(slab, ids, rows))
+        got = ops.session_scatter(slab, ids, rows, interpret=False)
+        check(np.array_equal(np.asarray(got), want),
+              f"arena leaf {i} {leaf.shape}: kernel scatter != .at[].set")
+    log(f"check arena kernels: gather and scatter equal XLA's bit for bit "
+        f"on {len(leaves)} leaves")
+    return len(leaves)
 
 
 def serve_phase(cfg, params, seed: int = 0) -> dict:
@@ -209,6 +262,11 @@ def serve_phase(cfg, params, seed: int = 0) -> dict:
         errs.extend(compare(sid, reqs[sid].result,
                             direct.query_logits(hist[sid], queries[sid]))
                     for sid in queries)
+        got = reqs["s0"].result
+        control("s0 without its last chunk", got,
+                direct.query_logits(hist["s0"][:-1], queries["s0"]))
+        control("s0 on s1's history", got,
+                direct.query_logits(hist["s1"], queries["s0"]))
 
     timed("direct_check", reference)
     return {"stats": eng.stats, "compile_stats": eng.compile_stats(),
@@ -223,8 +281,14 @@ def sharded_phase(cfg, params, seed: int = 0) -> dict:
     timings = {}
     n_sessions, n_shards = 8, 4
     rng = np.random.default_rng(seed)
-    traffic = [[rng.integers(0, cfg.vocab_size, n, dtype=np.int32)
-                for n in (CHUNK, CHUNK, QUERY, QUERY)]
+
+    def toks(n):
+        return rng.integers(0, cfg.vocab_size, n, dtype=np.int32)
+
+    # every session asks the same queries, so only its own rows tell its
+    # logits apart from another session's
+    queries = [toks(QUERY), toks(QUERY)]
+    traffic = [[toks(CHUNK), toks(CHUNK)] + queries
                for _ in range(n_sessions)]
     mesh = make_session_mesh(n_shards)
 
@@ -255,6 +319,10 @@ def sharded_phase(cfg, params, seed: int = 0) -> dict:
     errs = [compare(f"shard-vs-single s{i % n_sessions}", b.result,
                     np.asarray(a.result, np.float32))
             for i, (a, b) in enumerate(zip(r1, r4))]
+    for i in range(n_sessions):
+        j = (i + 1) % n_sessions
+        control(f"shard s{i} vs single s{j}", r4[i].result,
+                np.asarray(r1[j].result, np.float32))
     moves = eng4.obs.registry.get("serve_cross_shard_moves_total").value
     check(moves == 0, f"{moves} cross-shard moves")
     arena = eng4._mgr["online"].arena
@@ -316,8 +384,14 @@ def main(argv=None) -> int:
     log(f"model: {ARCH} layers={cfg.n_layers} d_model={cfg.d_model} "
         f"heads={cfg.n_heads}/{cfg.n_kv_heads} d_ff={cfg.d_ff} "
         f"vocab={cfg.vocab_size} params={n_params}")
-    phase = serve_phase if args.chips == 1 else sharded_phase
-    report = phase(cfg, params, seed=args.seed)
+    if args.chips == 1:
+        t0 = perf_counter()
+        kernel_phase(cfg, seed=args.seed)
+        kernel_s = perf_counter() - t0
+        report = serve_phase(cfg, params, seed=args.seed)
+        report["timings"] = {"arena_kernels": kernel_s, **report["timings"]}
+    else:
+        report = sharded_phase(cfg, params, seed=args.seed)
     timings = {"init_params": init_s, **report["timings"]}
     for name, sec in timings.items():
         log(f"smoke-timing (not a benchmark) {name}: {sec:.3f}")

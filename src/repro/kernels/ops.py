@@ -94,18 +94,18 @@ def cond_lora(x, w, a, b, gate, scale: float, block_m: int = 128,
     return out[:M, :N]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "sharded"))
-def session_gather(slab, ids, interpret: Optional[bool] = None,
-                   sharded: bool = False):
-    """Arena pack: slab (S, ...), ids (B,) int32 -> (B, ...) rows.
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def session_gather(slab, ids, interpret: Optional[bool] = None):
+    """Arena pack in the fused serve steps: slab (S, ...), ids (B,) int32
+    -> (B, ...) rows.
 
     TPU -> compiled Pallas DMA gather; elsewhere the pure-jnp ref (unless
     ``interpret=True`` forces the Pallas interpreter for validation).
-    ``sharded``: the slab spans several devices outside `shard_map` (a
-    mesh-placed arena's off-hot-path pack) — XLA's own gather, which the
-    partitioner can split; a Mosaic kernel cannot be partitioned.
+    The kernel reads a row-major ``(S, 1, R)`` view.  On TPU the slab's
+    native tiled layout is not row-major, so XLA relayouts the whole
+    slab into that view: a call moves O(S) rows, not O(B).
     """
-    if sharded or (interpret is None and _use_interpret()):
+    if interpret is None and _use_interpret():
         return _ref.session_gather_ref(slab, ids)
     S = slab.shape[0]
     out = _sess.session_gather(slab.reshape(S, 1, -1), ids,
@@ -113,14 +113,14 @@ def session_gather(slab, ids, interpret: Optional[bool] = None,
     return out.reshape((ids.shape[0],) + slab.shape[1:])
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "sharded"),
+@functools.partial(jax.jit, static_argnames=("interpret",),
                    donate_argnums=(0,))
-def session_scatter(slab, ids, rows, interpret: Optional[bool] = None,
-                    sharded: bool = False):
-    """Arena unpack: slab (S, ...) with slab[ids] = rows (B, ...), in place
-    (the slab argument is donated on both backends).  ``sharded`` as in
-    `session_gather`."""
-    if sharded or (interpret is None and _use_interpret()):
+def session_scatter(slab, ids, rows, interpret: Optional[bool] = None):
+    """Arena unpack: slab (S, ...) with slab[ids] = rows (B, ...).  The
+    slab argument is donated on both backends.  The kernel writes only
+    the B rows of its aliased view, but on TPU the view costs a
+    whole-slab relayout in and out, as in `session_gather`."""
+    if interpret is None and _use_interpret():
         return _ref.session_scatter_ref(slab, ids, rows)
     S = slab.shape[0]
     out = _sess.session_scatter(slab.reshape(S, 1, -1), ids,

@@ -14,7 +14,13 @@ rule) — for bf16 KV rows, memory rows and ``R == 1`` counters alike.
 
   session_gather  — rows = slab[ids]          (B, 1, R) out of (S, 1, R)
   session_scatter — slab[ids] = rows, in place via input/output aliasing
-                    (donated slab buffer; untouched rows are not copied)
+                    (the kernel writes only the B touched rows)
+
+Only the kernel moves O(B) rows.  A Mosaic operand is row-major, and
+XLA's native TPU layout for a serve-arena leaf such as
+``(S, L, 1, C, Hkv, D)`` bf16 is not (C is minor-most), so a caller
+holding the slab in that layout pays a whole-slab relayout into the
+view and back (`kernels.ops.session_gather`).
 
 Duplicate ids in ``session_scatter`` (the scheduler's padding rows all
 point at the arena's scratch slot) write the same row more than once;
@@ -70,8 +76,8 @@ def session_scatter(slab, ids, rows, block_cols: int = 16384,
     """slab (S, 1, R), ids (B,), rows (B, 1, R) -> slab with
     slab[ids] = rows.
 
-    The slab operand is aliased to the output, so only the B touched rows
-    move; everything else stays in the donated buffer.
+    The slab operand is aliased to the output: the kernel writes the B
+    touched rows and leaves the rest of the donated buffer alone.
     """
     S, _, R = slab.shape
     B = ids.shape[0]

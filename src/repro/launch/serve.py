@@ -27,6 +27,7 @@ from repro.core import inference as I
 from repro.core import streaming as STR
 from repro.distributed import sharding as SH
 from repro.distributed.context import DistContext, divisible
+from repro.kernels import ref as KREF
 from repro.models.config import ModelConfig
 
 
@@ -276,10 +277,9 @@ def make_sharded_arena_step(cfg: ModelConfig, op: str, mesh,
     return jax.jit(fn, donate_argnums=(1,))
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "group", "sharded"),
+@functools.partial(jax.jit, static_argnames=("cfg", "group"),
                    donate_argnums=(0,))
-def recompress_arena_slots(mem_slabs, ids, cfg: ModelConfig, group: int,
-                           sharded: bool = False):
+def recompress_arena_slots(mem_slabs, ids, cfg: ModelConfig, group: int):
     """Arena-resident memory recompression: gather the ``ids`` rows of
     the slabs' `MemState` subtree, collapse every ``group`` consecutive
     filled <COMP> groups per lane (`core.memory.recompress_memory`,
@@ -292,22 +292,18 @@ def recompress_arena_slots(mem_slabs, ids, cfg: ModelConfig, group: int,
     or pad lanes gathering the scratch row) are re-selected bit-exactly.
     Module-level jit: `ModelConfig` is hashable, so every engine —
     and every fuzzed simulation trace — shares one compile per
-    (shape, cfg, group).  ``sharded``: the slabs span a mesh (see
-    `kernels.ops.session_gather`)."""
-    from repro.kernels import ops as KOPS
-    mem = jax.tree.map(
-        lambda s: KOPS.session_gather(s, ids, sharded=sharded), mem_slabs)
+    (shape, cfg, group).  Off the hot path, so the rows move by XLA's
+    gather/scatter, which also partitions over a mesh-placed arena."""
+    mem = jax.tree.map(lambda s: KREF.session_gather_ref(s, ids), mem_slabs)
     # shrink only when it frees at least one group: ceil(g/r) < g
     do = -(-mem.slots // group) < mem.slots
     new = STR.recompress_memory_lanes(cfg, mem, group, do)
     return jax.tree.map(
-        lambda s, r: KOPS.session_scatter(s, ids, r, sharded=sharded),
-        mem_slabs, new)
+        lambda s, r: KREF.session_scatter_ref(s, ids, r), mem_slabs, new)
 
 
-@functools.partial(jax.jit, static_argnames=("sharded",),
-                   donate_argnums=(0,))
-def cow_clone_slots(slabs, src_ids, dst_ids, sharded: bool = False):
+@functools.partial(jax.jit, donate_argnums=(0,))
+def cow_clone_slots(slabs, src_ids, dst_ids):
     """Copy-on-write break: clone the ``src_ids`` rows of every slab
     leaf into the freshly-allocated ``dst_ids`` rows — one jitted
     gather/scatter over the donated slabs, batched over all of a shard's
@@ -320,14 +316,11 @@ def cow_clone_slots(slabs, src_ids, dst_ids, sharded: bool = False):
     its reference on the shared slot, and repoints the session — the
     siblings' view of the original row is never touched.  Module-level
     jit like `recompress_arena_slots`: every arena (engines, fuzzed
-    simulation traces) shares one compile per shape.  ``sharded``: the
-    slabs span a mesh (see `kernels.ops.session_gather`)."""
-    from repro.kernels import ops as KOPS
-    rows = jax.tree.map(
-        lambda s: KOPS.session_gather(s, src_ids, sharded=sharded), slabs)
+    simulation traces) shares one compile per shape.  XLA's
+    gather/scatter, as in `recompress_arena_slots`."""
+    rows = jax.tree.map(lambda s: KREF.session_gather_ref(s, src_ids), slabs)
     return jax.tree.map(
-        lambda s, r: KOPS.session_scatter(s, dst_ids, r, sharded=sharded),
-        slabs, rows)
+        lambda s, r: KREF.session_scatter_ref(s, dst_ids, r), slabs, rows)
 
 
 def make_null_step(cfg: ModelConfig, op: str, ragged: bool = False

@@ -38,9 +38,8 @@ exactly (``n_slots + 1`` rows, scratch at ``n_slots``).
 
 ``pack`` gathers any set of active slot ids into a contiguous batch for
 the vmapped session ops (`launch.serve.session_vmap`), and ``unpack``
-scatters the updated batch back — both one jitted gather/scatter over
-donated buffers (`kernels.ops.session_gather` / `session_scatter`,
-Pallas DMA on TPU).  The engine's hot path fuses all three into one
+scatters the updated batch back — both one jitted XLA gather/scatter
+over donated buffers.  The engine's hot path fuses all three into one
 program via `launch.serve.make_arena_step` (or, sharded, one
 `shard_map` program via `make_sharded_arena_step`); pack/unpack here
 serve the offload/restore and single-slot paths.
@@ -61,7 +60,7 @@ import jax.numpy as jnp
 
 from repro.core import inference as I
 from repro.core import streaming as STR
-from repro.kernels import ops
+from repro.kernels import ref
 from repro.models.config import ModelConfig
 
 
@@ -78,20 +77,17 @@ class ArenaFull(RuntimeError):
 # identity, so per-instance `jax.jit(...)` wrappers would recompile the
 # same gather/scatter for every arena built (one per fuzzed trace in
 # tests/simulation.py, one per engine elsewhere).
-# ``sharded``: the slabs span a mesh (a placed arena) and these run
-# outside `shard_map`, so they take XLA's partitionable gather/scatter.
-@functools.partial(jax.jit, static_argnames=("sharded",))
-def _pack_slabs(slabs, ids, sharded=False):
-    return jax.tree.map(
-        lambda slab: ops.session_gather(slab, ids, sharded=sharded), slabs)
+# XLA's own gather/scatter: it works on the slab in its native device
+# layout (no relayout copy) and partitions over a mesh-placed arena.
+@jax.jit
+def _pack_slabs(slabs, ids):
+    return jax.tree.map(lambda slab: ref.session_gather_ref(slab, ids), slabs)
 
 
-@functools.partial(jax.jit, static_argnames=("sharded",),
-                   donate_argnums=(0,))
-def _scatter_slabs(slabs, ids, state, sharded=False):
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _scatter_slabs(slabs, ids, state):
     return jax.tree.map(
-        lambda slab, rows: ops.session_scatter(slab, ids, rows,
-                                               sharded=sharded),
+        lambda slab, rows: ref.session_scatter_ref(slab, ids, rows),
         slabs, state)
 
 
@@ -326,15 +322,14 @@ class SessionArena:
     def pack(self, slot_ids: Sequence[int]):
         """Gather slots into a batch: leaves (B,) + template shape."""
         ids = jnp.asarray(slot_ids, jnp.int32)
-        return self._pack(self.slabs, ids, sharded=self.placed)
+        return self._pack(self.slabs, ids)
 
     def unpack(self, slot_ids: Sequence[int], state) -> None:
         """Scatter an updated batch back (donates slabs + batch)."""
         self._guard_writes(slot_ids)
         ids = jnp.asarray(slot_ids, jnp.int32)
         self._dirty.update(int(i) for i in slot_ids)
-        self.slabs = self._scatter(self.slabs, ids, state,
-                                   sharded=self.placed)
+        self.slabs = self._scatter(self.slabs, ids, state)
 
     def mark_dirty(self, slot_ids: Sequence[int]) -> None:
         """Record external writes (the engine's fused step updates

@@ -810,7 +810,7 @@ class ServeEngine:
         arena = mgr.arena
         arena.slabs = arena.slabs._replace(mem=SRV.recompress_arena_slots(
             arena.slabs.mem, jnp.asarray([sess.slot], jnp.int32),
-            cfg=self.cfg, group=group, sharded=arena.placed))
+            cfg=self.cfg, group=group))
         arena.mark_dirty([sess.slot])
         sess.mem_groups = new_groups
         return freed

@@ -617,8 +617,7 @@ class SessionManager:
             src_ids = np.asarray(src + [pad] * (n - len(src)), np.int32)
             dst_ids = np.asarray(dst + [pad] * (n - len(dst)), np.int32)
             self.arena.slabs = cow_clone_slots(
-                self.arena.slabs, src_ids, dst_ids,
-                sharded=self.arena.placed)
+                self.arena.slabs, src_ids, dst_ids)
             for sess, new in zip(group, dst):
                 old = sess.slot
                 sess.slot = new

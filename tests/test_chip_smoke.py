@@ -1,4 +1,4 @@
-"""chip_smoke.py on the CPU: its serve phase at the smoke config, and its
+"""chip_smoke.py on the CPU: its phases at the smoke config, and its
 refusal to run without a TPU."""
 import importlib.util
 import os
@@ -37,6 +37,20 @@ def test_chip_smoke_serve_phase_at_smoke_config():
     assert np.isfinite(report["max_abs_err"])
     assert {"ingest_1", "fork_query", "stream", "direct_check"} \
         <= set(report["timings"])
+
+
+def test_chip_smoke_kernel_phase_in_interpret_mode(monkeypatch):
+    """The arena-kernel check at the smoke config, with the kernels in
+    the Pallas interpreter (the script itself compiles them)."""
+    from repro.kernels import ops
+    smoke = _load_smoke()
+    gather, scatter = ops.session_gather, ops.session_scatter
+    monkeypatch.setattr(ops, "session_gather", lambda s, i, interpret:
+                        gather(s, i, interpret=True))
+    monkeypatch.setattr(ops, "session_scatter", lambda s, i, r, interpret:
+                        scatter(s, i, r, interpret=True))
+    cfg = get_config("qwen2-0.5b", smoke=True)
+    assert smoke.kernel_phase(cfg) > 0
 
 
 def test_chip_smoke_refuses_cpu(tmp_path):

@@ -10,6 +10,8 @@ topology is described inside a fixture — never at import — so every
 test worker collects the same tests and only the one that runs this
 file loads the TPU compiler.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -191,10 +193,31 @@ def _mesh_slabs(mesh, cfg, rows_per_shard=3):
         online_template(cfg, CACHE_LEN))
 
 
-def test_mesh_arena_offpath_compiles(mesh4, tpu_branch):
-    """Off-hot-path pack/unpack/COW on mesh-placed slabs: a Mosaic kernel
-    cannot be partitioned outside shard_map, so ``sharded=True`` must
-    route them to XLA's gather/scatter (the TPU branch is forced here)."""
+def _slab_copies(text, n_rows):
+    """``copy`` ops in compiled HLO whose result has a slab's row axis."""
+    return [line for line in text.splitlines()
+            if re.search(rf"= \w+\[{n_rows},[^=]* copy(-start)?\(", line)]
+
+
+def test_arena_offpath_in_place(spec):
+    """Off-hot-path pack/unpack/COW run XLA's gather/scatter, which works
+    in the slab's native layout: no relayout copy of a whole slab."""
+    from repro.launch.serve import cow_clone_slots
+    from repro.serve import arena as AR
+    slabs = jax.tree.map(lambda s: spec((N_ROWS,) + s.shape, s.dtype),
+                         online_template(QWEN, CACHE_LEN))
+    ids = spec((LANES,), jnp.int32)
+    rows = jax.tree.map(lambda s: spec((LANES,) + s.shape[1:], s.dtype),
+                        slabs)
+    for compiled in (AR._pack_slabs.lower(slabs, ids).compile(),
+                     AR._scatter_slabs.lower(slabs, ids, rows).compile(),
+                     cow_clone_slots.lower(slabs, ids, ids).compile()):
+        assert _slab_copies(compiled.as_text(), N_ROWS) == []
+
+
+def test_mesh_arena_offpath_compiles(mesh4):
+    """The same programs on mesh-placed slabs: XLA partitions them (a
+    Mosaic kernel could not be partitioned outside shard_map)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.launch.serve import cow_clone_slots
     from repro.serve import arena as AR
@@ -203,9 +226,9 @@ def test_mesh_arena_offpath_compiles(mesh4, tpu_branch):
                                sharding=NamedSharding(mesh4, P()))
     rows = jax.tree.map(lambda s: jax.ShapeDtypeStruct((2,) + s.shape[1:],
                                                        s.dtype), slabs)
-    AR._pack_slabs.lower(slabs, ids, sharded=True).compile()
-    AR._scatter_slabs.lower(slabs, ids, rows, sharded=True).compile()
-    cow_clone_slots.lower(slabs, ids, ids, sharded=True).compile()
+    AR._pack_slabs.lower(slabs, ids).compile()
+    AR._scatter_slabs.lower(slabs, ids, rows).compile()
+    cow_clone_slots.lower(slabs, ids, ids).compile()
 
 
 @pytest.mark.parametrize("op", ["ingest", "query"])
