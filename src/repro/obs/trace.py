@@ -11,17 +11,26 @@ property the simulation suite asserts.  Timestamps come from the
 recorder's injected `Clock` (`obs.clock`), so the deterministic
 simulation harness produces byte-identical traces run to run.
 
+Beside the per-request events, ``recorder.span(name)`` times one named
+host phase of the engine (``serve.pop``, ``serve.fetch``...): the
+seconds spent inside it, on the bound clock, add to the always-on
+counter ``serve_host_seconds_total{span}`` whichever recorder is bound.
+
 Two recorder implementations share one call surface:
 
-  NullRecorder  — the default: every hook is a no-op ``pass`` (no
-                  allocation, no clock reads), so an engine without
+  NullRecorder  — the default: every request hook is a no-op ``pass``
+                  (no allocation, no clock reads), so an engine without
                   tracing behaves bit-exactly like one that never heard
-                  of this module.
+                  of this module; a span is one reused object that reads
+                  the clock twice and makes no profiler call.
   TraceRecorder — keeps per-request `RequestTrace`s (bounded completed
                   ring), feeds queue-wait / end-to-end latency
                   histograms into the bound `MetricsRegistry`, and logs
                   every event into a bounded ring-buffer
-                  `FlightRecorder` the engine dumps on error.
+                  `FlightRecorder` the engine dumps on error; a span
+                  also enters a ``jax.profiler.TraceAnnotation``, so a
+                  running profiler records it on the device trace's
+                  clock.
 
 The recorder observes; it never steers.  Engine/session code calls the
 hooks with live `Request` objects (duck-typed: ``.sid``/``.kind``/
@@ -100,14 +109,79 @@ class FlightRecorder:
                 for ts, event, detail in self._ring]
 
 
+class HostSpan:
+    """One named host phase.  The seconds spent inside it, read on the
+    bound clock, add to ``serve_host_seconds_total{span=name}``.  One
+    object per (recorder, name), reused by every ``with``; nested
+    entries of the same name each count."""
+    __slots__ = ("name", "_clock", "_seconds", "_starts")
+
+    def __init__(self, name: str, clock, seconds):
+        self.name = name
+        self._clock = clock
+        self._seconds = seconds          # the family's child for name
+        self._starts: List[float] = []
+
+    def __enter__(self):
+        self._starts.append(self._clock.now())
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._seconds.inc(self._clock.now() - self._starts.pop())
+        return False
+
+
+class AnnotatedSpan(HostSpan):
+    """A `HostSpan` that also enters ``jax.profiler.TraceAnnotation``
+    (imported here, so ``obs`` imports without jax): a running
+    profiler records the span on the host plane of its trace, on the
+    clock the device events share."""
+    __slots__ = ("_annotation", "_open")
+
+    def __init__(self, name: str, clock, seconds):
+        super().__init__(name, clock, seconds)
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
+        self._open: list = []
+
+    def __enter__(self):
+        ann = self._annotation(self.name)
+        ann.__enter__()
+        self._open.append(ann)
+        return super().__enter__()
+
+    def __exit__(self, *exc) -> bool:
+        super().__exit__(*exc)
+        self._open.pop().__exit__(*exc)
+        return False
+
+
 class NullRecorder:
-    """Do-nothing recorder: the engine's default.  Every hook is a bare
-    ``pass`` — no clock reads, no allocation — so the disabled path is
-    bit-exact with (and as fast as) a never-instrumented engine."""
+    """Do-nothing recorder: the engine's default.  Every request hook is
+    a bare ``pass`` — no clock reads, no allocation — so the disabled
+    path is bit-exact with (and as fast as) a never-instrumented engine.
+    Spans still time host phases into the registry (`HostSpan`)."""
     enabled = False
+    _span_type = HostSpan
 
     def bind(self, clock, registry) -> None:
-        pass
+        self._span_clock = clock
+        self._host_seconds = registry.counter(
+            "serve_host_seconds_total", "host seconds inside each named engine phase "
+            "(serve.pop, serve.fetch, ...), on the bound clock",
+            labels=("span",))
+        self._spans: Dict[str, HostSpan] = {}
+
+    # -- host phases -------------------------------------------------
+    def span(self, name: str):
+        """Context manager timing the host phase ``name`` (the recorder
+        must be bound); the same object on every call with that name."""
+        s = self._spans.get(name)
+        if s is None:
+            s = self._spans[name] = self._span_type(
+                name, self._span_clock,
+                self._host_seconds.labels(span=name))
+        return s
 
     # -- request lifecycle --------------------------------------------
     def submit(self, req) -> None:
@@ -160,6 +234,7 @@ class TraceRecorder(NullRecorder):
     (the scheduler queue, the engine ledger, and test drivers all do),
     so identity is stable from submit to terminal."""
     enabled = True
+    _span_type = AnnotatedSpan
 
     def __init__(self, clock=None, registry: Optional[MetricsRegistry] = None,
                  flight_capacity: int = 256, keep_completed: int = 4096):
@@ -178,6 +253,7 @@ class TraceRecorder(NullRecorder):
         if clock is not None:
             self.clock = clock
         self._registry = registry
+        super().bind(self.clock, registry)
         self._h_wait = registry.histogram(
             "serve_queue_wait_seconds",
             "seconds between admission into the scheduler queue and the "

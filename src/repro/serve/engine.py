@@ -15,15 +15,18 @@ backpressure backlog is pumped, so blocked submits drain as soon as
 queue capacity frees.
 
 OBSERVABILITY (`repro.obs`, see docs/OBSERVABILITY.md): every counter
-the engine keeps — per-op requests/tokens/padding waste, dispatch
-seconds, compile churn, admission verdicts, offload transfer
-bytes/seconds — lives in one `MetricsRegistry`, exported as JSON
-(`metrics_snapshot`) or Prometheus text (`metrics_prometheus`); the
-legacy ``stats`` dicts remain as thin read-only views.  Pass
+the engine keeps — per-op requests/tokens/padding waste, result bytes,
+host seconds per drain phase, compile churn, admission verdicts,
+offload transfer bytes/seconds — lives in one `MetricsRegistry`,
+exported as JSON (`metrics_snapshot`) or Prometheus text
+(`metrics_prometheus`); the legacy ``stats`` dicts remain as thin
+read-only views.  Each phase of a drain runs under a named host span
+(`HOST_SPANS`), timed into ``serve_host_seconds_total{span}``.  Pass
 ``obs=Observability.tracing()`` for per-request lifecycle spans
 (submit -> verdict -> queue wait -> execute -> terminal), queue-wait /
-end-to-end latency histograms, and a bounded flight recorder the
-engine dumps to stderr when an exception escapes a drain.  The default
+end-to-end latency histograms, a bounded flight recorder the engine
+dumps to stderr when an exception escapes a drain, and the drain's host
+spans on a running ``jax.profiler`` trace.  The default
 `NullRecorder` makes every trace hook a no-op — cache state and
 verdicts are bit-exact with a recorder-enabled run on the same
 traffic, and all timing stays outside jit (device work is timed around
@@ -76,6 +79,11 @@ from repro.serve.session import (CloseResult, OffloadCostModel,
                                  OffloadResult, SessionManager)
 
 _OP_STATE = {"ingest": "online", "query": "online", "stream": "stream"}
+# host phases of a drain (docs/OBSERVABILITY.md "Engine spans"): every
+# other span runs inside serve.drain; fetch and deliver once per batch
+HOST_SPANS = ("serve.drain", "serve.pop", "serve.activate", "serve.pack",
+              "serve.dispatch", "serve.fetch", "serve.deliver",
+              "serve.sync")
 _STAT_KEYS = ("requests", "tokens", "pad_lanes", "pad_tokens", "lanes",
               "batches")
 
@@ -371,10 +379,10 @@ class ServeEngine:
             "batches": reg.counter(
                 "serve_batches_total", "batches dispatched",
                 labels=("kind",)),
-            "dispatch_s": reg.counter(
-                "serve_dispatch_seconds_total",
-                "host time spent dispatching fused steps (async — the "
-                "synced drain wall clock is serve_wall_seconds_total)",
+            "result_bytes": reg.counter(
+                "serve_result_bytes_total",
+                "bytes of the result arrays copied to the host, pad "
+                "lanes and pad positions included, per op kind",
                 labels=("kind",)),
             "wall_s": reg.counter(
                 "serve_wall_seconds_total",
@@ -387,9 +395,11 @@ class ServeEngine:
         }
         # pre-create per-kind children so exports carry explicit zeros
         for fam in ("requests", "tokens", "pad_lanes", "pad_tokens",
-                    "lanes", "batches", "dispatch_s"):
+                    "lanes", "batches", "result_bytes"):
             for k in _OP_STATE:
                 self._m[fam].labels(kind=k)
+        for name in HOST_SPANS:
+            self.obs.recorder.span(name)
         self._m_deadline = {
             "requests": reg.counter(
                 "serve_deadline_requests_total",
@@ -1016,29 +1026,34 @@ class ServeEngine:
         arena = mgr.arena
         rec = self.obs.recorder
         pinned = {r.sid for r in batch.requests}
-        t0 = self.obs.clock.now()
-        slots = mgr.activate_batch([r.sid for r in batch.requests], pinned)
-        ids = slots + [arena.pad_slot] * batch.pad
-        # lanes padded up to the batch's token bucket; per-lane valid
-        # lengths drive the masked ops (pad lanes claim the full bucket —
-        # they gather/scatter the scratch row, semantics don't matter)
-        toks = np.zeros((batch.bucket, 1, batch.token_len), np.int32)
-        for i, r in enumerate(batch.requests):
-            toks[i, 0, :r.token_len] = r.tokens[0]
-        lengths = np.asarray(batch.valid_lens
-                             + [batch.token_len] * batch.pad, np.int32)
+        with rec.span("serve.activate"):
+            slots = mgr.activate_batch([r.sid for r in batch.requests],
+                                       pinned)
+        with rec.span("serve.pack"):
+            ids = slots + [arena.pad_slot] * batch.pad
+            # lanes padded up to the batch's token bucket; per-lane valid
+            # lengths drive the masked ops (pad lanes claim the full
+            # bucket — they gather/scatter the scratch row, semantics
+            # don't matter)
+            toks = np.zeros((batch.bucket, 1, batch.token_len), np.int32)
+            for i, r in enumerate(batch.requests):
+                toks[i, 0, :r.token_len] = r.tokens[0]
+            lengths = np.asarray(batch.valid_lens
+                                 + [batch.token_len] * batch.pad, np.int32)
+            lane_ids = jnp.asarray(ids, jnp.int32)
         # one fused jitted program: gather rows -> vmapped op -> scatter
         # rows back into the donated slabs.  No block here: batches chain
         # through the slab dependency and overlap Python scheduling;
         # run() syncs once at the end of the drain.
         masked = self.ragged and any(vl != batch.token_len
                                      for vl in batch.valid_lens)
-        step = self._step(batch.kind, masked)
-        self._note_shape(batch.kind, batch.bucket, batch.token_len, masked)
-        out, arena.slabs = step(self.params, arena.slabs,
-                                jnp.asarray(ids, jnp.int32), toks, lengths)
+        with rec.span("serve.dispatch"):
+            step = self._step(batch.kind, masked)
+            self._note_shape(batch.kind, batch.bucket, batch.token_len,
+                             masked)
+            out, arena.slabs = step(self.params, arena.slabs, lane_ids,
+                                    toks, lengths)
         arena.mark_dirty(ids)
-        dt = self.obs.clock.now() - t0
         # results are NOT materialized here — np.asarray(out) would
         # block on this batch's compute and serialize the drain; run()
         # converts all outs after the last dispatch (one transfer per
@@ -1059,8 +1074,7 @@ class ServeEngine:
             mgr.record(r.sid, r.kind, r.tokens[0])
             rec.executed(r, shape)
         rec.note("batch", f"kind={batch.kind} shape={shape} "
-                          f"real={len(batch.requests)} pad={batch.pad} "
-                          f"dispatch_s={dt:.6f}")
+                          f"real={len(batch.requests)} pad={batch.pad}")
         m = self._m
         m["requests"].labels(kind=batch.kind).inc(len(batch.requests))
         m["tokens"].labels(kind=batch.kind).inc(sum(batch.valid_lens))
@@ -1069,7 +1083,6 @@ class ServeEngine:
             len(batch.requests) * batch.token_len - sum(batch.valid_lens))
         m["lanes"].labels(kind=batch.kind).inc(batch.bucket)
         m["batches"].labels(kind=batch.kind).inc()
-        m["dispatch_s"].labels(kind=batch.kind).inc(dt)
 
     def _run_sharded_batch(self, sb: ShardedBatch) -> None:
         """Execute one sharded pop: activate every sub-batch's sessions
@@ -1084,52 +1097,58 @@ class ServeEngine:
         rec = self.obs.recorder
         all_reqs = sb.requests                       # shard-major
         pinned = {r.sid for r in all_reqs}
-        t0 = self.obs.clock.now()
-        slots = mgr.activate_batch([r.sid for r in all_reqs], pinned)
+        with rec.span("serve.activate"):
+            slots = mgr.activate_batch([r.sid for r in all_reqs], pinned)
         slot_of = dict(zip((r.sid for r in all_reqs), slots))
         S, B, L = self.n_shards, sb.bucket, sb.token_len
         use_mesh = self.mesh is not None
-        # mesh mode feeds LOCAL row ids (each device indexes its own
-        # block under shard_map); loop mode feeds global slot ids
-        ids = np.empty((S, B), np.int32)
-        toks = np.zeros((S, B, 1, L), np.int32)
-        lengths = np.full((S, B), L, np.int32)
-        gids: List[int] = []                         # global, for dirty
-        for s, sub in enumerate(sb.shards):
-            pad = arena.pad_slot_of(s)
-            ids[s, :] = arena.local_row(pad) if use_mesh else pad
-            for i, r in enumerate(sub.requests):
-                slot = slot_of[r.sid]
-                ids[s, i] = arena.local_row(slot) if use_mesh else slot
-                toks[s, i, 0, :r.token_len] = r.tokens[0]
-                lengths[s, i] = r.token_len
-                gids.append(slot)
-            gids.extend([pad] * (B - len(sub.requests)))
+        with rec.span("serve.pack"):
+            # mesh mode feeds LOCAL row ids (each device indexes its own
+            # block under shard_map); loop mode feeds global slot ids
+            ids = np.empty((S, B), np.int32)
+            toks = np.zeros((S, B, 1, L), np.int32)
+            lengths = np.full((S, B), L, np.int32)
+            gids: List[int] = []                     # global, for dirty
+            for s, sub in enumerate(sb.shards):
+                pad = arena.pad_slot_of(s)
+                ids[s, :] = arena.local_row(pad) if use_mesh else pad
+                for i, r in enumerate(sub.requests):
+                    slot = slot_of[r.sid]
+                    ids[s, i] = arena.local_row(slot) if use_mesh else slot
+                    toks[s, i, 0, :r.token_len] = r.tokens[0]
+                    lengths[s, i] = r.token_len
+                    gids.append(slot)
+                gids.extend([pad] * (B - len(sub.requests)))
+            if use_mesh:
+                lane_ids = jnp.asarray(ids, jnp.int32)
+            else:
+                lane_ids = [jnp.asarray(ids[s], jnp.int32)
+                            if sub.requests else None
+                            for s, sub in enumerate(sb.shards)]
         masked = self.ragged and any(r.token_len != L for r in all_reqs)
         lanes_run = S * B
-        if use_mesh:
-            step = self._sharded_step(sb.kind, masked)
-            self._note_shape(sb.kind, B, L, masked)
-            out, arena.slabs = step(
-                self.params, arena.slabs, jnp.asarray(ids, jnp.int32),
-                toks, lengths)
-            outs = [None if out is None else out[s] for s in range(S)]
-        else:
-            step = self._step(sb.kind, masked)
-            self._note_shape(sb.kind, B, L, masked)
-            outs = []
-            lanes_run = 0
-            for s, sub in enumerate(sb.shards):
-                if not sub.requests:
-                    outs.append(None)
-                    continue
-                out_s, arena.slabs = step(
-                    self.params, arena.slabs,
-                    jnp.asarray(ids[s], jnp.int32), toks[s], lengths[s])
-                outs.append(out_s)
-                lanes_run += B
+        with rec.span("serve.dispatch"):
+            if use_mesh:
+                step = self._sharded_step(sb.kind, masked)
+                self._note_shape(sb.kind, B, L, masked)
+                out, arena.slabs = step(self.params, arena.slabs,
+                                        lane_ids, toks, lengths)
+                outs = [None if out is None else out[s] for s in range(S)]
+            else:
+                step = self._step(sb.kind, masked)
+                self._note_shape(sb.kind, B, L, masked)
+                outs = []
+                lanes_run = 0
+                for s, sub in enumerate(sb.shards):
+                    if not sub.requests:
+                        outs.append(None)
+                        continue
+                    out_s, arena.slabs = step(
+                        self.params, arena.slabs, lane_ids[s], toks[s],
+                        lengths[s])
+                    outs.append(out_s)
+                    lanes_run += B
         arena.mark_dirty(gids)
-        dt = self.obs.clock.now() - t0
         for s, sub in enumerate(sb.shards):
             if sub.requests:
                 self._undelivered.append((sub.requests, outs[s]))
@@ -1146,8 +1165,7 @@ class ServeEngine:
         valid = sum(r.token_len for r in all_reqs)
         rec.note("batch", f"kind={sb.kind} shape={shape} "
                           f"real={len(all_reqs)} "
-                          f"pad={lanes_run - len(all_reqs)} "
-                          f"dispatch_s={dt:.6f}")
+                          f"pad={lanes_run - len(all_reqs)}")
         m = self._m
         m["requests"].labels(kind=sb.kind).inc(len(all_reqs))
         m["tokens"].labels(kind=sb.kind).inc(valid)
@@ -1156,7 +1174,6 @@ class ServeEngine:
             len(all_reqs) * L - valid)
         m["lanes"].labels(kind=sb.kind).inc(lanes_run)
         m["batches"].labels(kind=sb.kind).inc()
-        m["dispatch_s"].labels(kind=sb.kind).inc(dt)
 
     def run(self, max_batches: Optional[int] = None) -> int:
         """Drain the queue (or up to ``max_batches``); returns batches
@@ -1164,12 +1181,13 @@ class ServeEngine:
         backpressured submits enter the queue as soon as their tokens
         fit — and the drain only ends once both the queue AND the
         pumpable backlog are empty.  Synchronizes once at the end, so
-        per-kind dispatch seconds are dispatch times and the drain's
-        wall clock is the true cost.  If anything escapes mid-drain the
-        flight recorder's last events are dumped to stderr before the
-        exception propagates."""
+        the drain's wall clock is the true cost; each phase of the drain
+        is timed under its host span (`HOST_SPANS`).  If anything
+        escapes mid-drain the flight recorder's last events are dumped
+        to stderr before the exception propagates."""
         try:
-            return self._run(max_batches)
+            with self.obs.recorder.span("serve.drain"):
+                return self._run(max_batches)
         except Exception as exc:                 # noqa: BLE001 — re-raised
             self._dump_flight_on_error(exc)
             raise
@@ -1190,27 +1208,31 @@ class ServeEngine:
                 self.refit_token_buckets()
             self._popping = True
             try:
-                # recomputed per pop: pumped backlog entries can
-                # introduce tenants that were not queued when the drain
-                # started
-                caps, default_cap = self.admission.lane_caps()
-                if self.n_shards == 1:
-                    batch = self.scheduler.next_batch(caps, default_cap)
-                else:
-                    batch = self.scheduler.next_sharded_batches(
-                        self.n_shards, caps, default_cap,
-                        per_shard_cap=self._per_shard_cap,
-                        max_total=self._max_total)
-                if batch is None:
-                    pumped = self.admission.pump()
-                    if pumped:
+                with rec.span("serve.pop"):
+                    # recomputed per pop: pumped backlog entries can
+                    # introduce tenants that were not queued when the
+                    # drain started
+                    caps, default_cap = self.admission.lane_caps()
+                    if self.n_shards == 1:
+                        batch = self.scheduler.next_batch(caps,
+                                                          default_cap)
+                    else:
+                        batch = self.scheduler.next_sharded_batches(
+                            self.n_shards, caps, default_cap,
+                            per_shard_cap=self._per_shard_cap,
+                            max_total=self._max_total)
+                    if batch is None:
+                        pumped = self.admission.pump()
                         for r in pumped:
                             rec.pumped(r)
+                    else:
+                        self.admission.note_popped(batch.requests)
+                        for r in batch.requests:
+                            rec.popped(r)
+                if batch is None:
+                    if pumped:
                         continue
                     break
-                self.admission.note_popped(batch.requests)
-                for r in batch.requests:
-                    rec.popped(r)
                 if batch.kind == "fork":
                     # control-plane only: snapshot the parent at its
                     # program-order point — no device step runs
@@ -1226,8 +1248,9 @@ class ServeEngine:
                     # check — re-absorb past the high watermark so the
                     # next submit doesn't start from a deep deficit
                     self.pressure.maybe_relieve()
-                for r in self.admission.pump():
-                    rec.pumped(r)
+                with rec.span("serve.pop"):
+                    for r in self.admission.pump():
+                        rec.pumped(r)
                 n += 1
             finally:
                 self._popping = False
@@ -1237,32 +1260,45 @@ class ServeEngine:
         if n:
             now = self.obs.clock.now()
             for reqs, out in self._undelivered:
-                out_np = np.asarray(out) if out is not None else None
-                for i, r in enumerate(reqs):
-                    # slice off bucket padding: a request padded into a
-                    # larger token lane only owns its first valid_len
-                    # logit rows (the rest are masked-lane garbage)
-                    r.result = out_np[i, 0, :r.token_len] \
-                        if out_np is not None else None
-                    r.done = True
-                    if r.deadline is not None:
-                        if now > r.deadline:
-                            self._m_deadline["missed"].labels(
-                                kind=r.kind).inc()
-                            self._h_lateness.observe(now - r.deadline)
-                        else:
-                            self._m_deadline["met"].labels(
-                                kind=r.kind).inc()
-                    rec.finished(r)
+                out_np = None
+                if out is not None:
+                    # the bytes follow from the shape: no sync here
+                    self._m["result_bytes"].labels(kind=reqs[0].kind).inc(
+                        out.size * out.dtype.itemsize)
+                    # waits for this batch's compute, then copies it
+                    with rec.span("serve.fetch"):
+                        out_np = np.asarray(out)
+                with rec.span("serve.deliver"):
+                    for i, r in enumerate(reqs):
+                        # slice off bucket padding: a request padded
+                        # into a larger token lane only owns its first
+                        # valid_len logit rows (the rest are masked-lane
+                        # garbage)
+                        r.result = out_np[i, 0, :r.token_len] \
+                            if out_np is not None else None
+                        r.done = True
+                        if r.deadline is not None:
+                            if now > r.deadline:
+                                self._m_deadline["missed"].labels(
+                                    kind=r.kind).inc()
+                                self._h_lateness.observe(now - r.deadline)
+                            else:
+                                self._m_deadline["met"].labels(
+                                    kind=r.kind).inc()
+                        rec.finished(r)
             self._undelivered.clear()
-        for m in self._mgr.values():
-            # unconditional: async offload_session() transfers may be in
-            # flight even when this drain popped zero batches — leaving
-            # them unbarriered would pin the stacked host buffers forever
-            m.sync()
-        if n:
+        with rec.span("serve.sync"):
             for m in self._mgr.values():
-                jax.block_until_ready(jax.tree.leaves(m.arena.slabs)[0])
+                # unconditional: async offload_session() transfers may be
+                # in flight even when this drain popped zero batches —
+                # leaving them unbarriered would pin the stacked host
+                # buffers forever
+                m.sync()
+            if n:
+                for m in self._mgr.values():
+                    jax.block_until_ready(
+                        jax.tree.leaves(m.arena.slabs)[0])
+        if n:
             self._m["wall_s"].inc(self.obs.clock.now() - t0)
         if self._refit_pending:
             # a refit deferred by the final pop (the loop broke before
@@ -1295,15 +1331,12 @@ class ServeEngine:
     @property
     def stats(self) -> Dict[str, Dict[str, float]]:
         """Legacy per-kind stats view, now read from the registry
-        (``serve_*_total{kind}``).  ``seconds`` are dispatch times only;
-        the synced drain wall clock is ``stats_wall``."""
-        out = {}
-        for k in _OP_STATE:
-            out[k] = {key: int(self._m[key].labels(kind=k).value)
-                      for key in _STAT_KEYS}
-            out[k]["seconds"] = float(
-                self._m["dispatch_s"].labels(kind=k).value)
-        return out
+        (``serve_*_total{kind}``); the synced drain wall clock is
+        ``stats_wall``, host seconds per drain phase are
+        ``serve_host_seconds_total{span}``."""
+        return {k: {key: int(self._m[key].labels(kind=k).value)
+                    for key in _STAT_KEYS}
+                for k in _OP_STATE}
 
     @property
     def stats_wall(self) -> float:
@@ -1419,8 +1452,7 @@ class ServeEngine:
         return self.scheduler.pending + len(self.admission.backlog)
 
     def throughput(self) -> float:
-        """Overall tokens/s across all drains (synced wall clock).
-        Per-kind ``stats[kind]['seconds']`` are dispatch times only."""
+        """Overall tokens/s across all drains (synced wall clock)."""
         total = sum(s["tokens"] for s in self.stats.values())
         wall = self.stats_wall
         return total / wall if wall else 0.0

@@ -492,6 +492,177 @@ def test_trace_recorder_memory_bounded(tiny_cfg):
     assert len(rec._completed_by_key) <= 16    # pruned at 2x maxlen
 
 
+# -- engine host spans --------------------------------------------------
+
+def test_null_recorder_span_is_reused_and_never_profiles(monkeypatch):
+    """A NullRecorder span is one object per name, reused by every call,
+    and times into the registry without touching the profiler."""
+    import jax.profiler
+    from repro.obs.trace import NullRecorder
+
+    def no_profiler(*a, **k):
+        raise AssertionError("NullRecorder entered the profiler")
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", no_profiler)
+    clock = ManualClock()
+    obs = Observability(clock=clock)
+    rec = obs.recorder
+    assert isinstance(rec, NullRecorder)
+    assert rec.span("serve.pop") is rec.span("serve.pop")
+    assert rec.span("serve.pop") is not rec.span("serve.fetch")
+    with rec.span("serve.pop"):
+        clock.advance(2.5)
+        with rec.span("serve.pop"):            # nested entries each count
+            clock.advance(1.0)
+    fam = obs.registry.get("serve_host_seconds_total")
+    assert fam.labels(span="serve.pop").value == 4.5
+
+
+class _SlowResult:
+    """A device result whose host copy advances the manual clock."""
+
+    def __init__(self, out, tick):
+        self._out, self._tick = out, tick
+        self.size, self.dtype = out.size, out.dtype
+
+    def __array__(self, dtype=None, copy=None):
+        self._tick("serve.fetch", 16.0)
+        return np.asarray(self._out)
+
+
+@pytest.mark.parametrize("n_shards", [1, 2])
+def test_host_spans_hold_the_time_advanced_inside(
+        tiny_cfg, tiny_params, monkeypatch, n_shards):
+    """Under a ManualClock each serve.* child of serve_host_seconds_total
+    holds exactly the time advanced inside its phase, and serve.drain
+    the sum of them all, on the single and the sharded (loop) path."""
+    import types
+
+    import jax.numpy as jnp
+
+    from repro.serve import ServeEngine
+    from repro.serve import engine as E
+    clock = ManualClock()
+    eng = ServeEngine(tiny_params, tiny_cfg, n_slots=4, cache_len=32,
+                      batch_buckets=(1, 2, 4), n_shards=n_shards,
+                      obs=Observability(clock=clock))
+    advanced = {name: 0.0 for name in E.HOST_SPANS}
+
+    def tick(span, dt):
+        advanced[span] += dt
+        clock.advance(dt)
+
+    def ticking(span, dt, fn):
+        def wrapped(*a, **k):
+            tick(span, dt)
+            return fn(*a, **k)
+        return wrapped
+
+    sched = eng.scheduler
+    for name in ("next_batch", "next_sharded_batches"):
+        monkeypatch.setattr(sched, name,
+                            ticking("serve.pop", 1.0, getattr(sched, name)))
+    for mgr in eng._mgr.values():
+        monkeypatch.setattr(mgr, "activate_batch", ticking(
+            "serve.activate", 2.0, mgr.activate_batch))
+        monkeypatch.setattr(mgr, "sync",
+                            ticking("serve.sync", 64.0, mgr.sync))
+    monkeypatch.setattr(E, "jnp", types.SimpleNamespace(
+        asarray=ticking("serve.pack", 4.0, jnp.asarray), int32=jnp.int32))
+    real_step = eng._step
+
+    def slow_step(op, masked):
+        step = real_step(op, masked)
+
+        def run(*a):
+            tick("serve.dispatch", 8.0)
+            out, slabs = step(*a)
+            return (None if out is None else _SlowResult(out, tick)), slabs
+        return run
+    monkeypatch.setattr(eng, "_step", slow_step)
+    monkeypatch.setattr(eng.obs.recorder, "finished",
+                        lambda req: tick("serve.deliver", 32.0))
+    for s in range(3):
+        eng.create_session(f"u{s}")
+        eng.ingest(f"u{s}", np.arange(3 + s, dtype=np.int32))
+        eng.query(f"u{s}", np.arange(4, dtype=np.int32))
+    assert eng.run() > 0
+    fam = eng.obs.registry.get("serve_host_seconds_total")
+    got = {key[0]: child.value for key, child in fam.children()}
+    drain = got.pop("serve.drain")
+    advanced.pop("serve.drain")
+    assert got == advanced
+    assert all(v > 0 for v in advanced.values()), advanced
+    assert drain == sum(advanced.values())
+
+
+def test_result_bytes_count_every_fetched_logit(tiny_cfg, tiny_params):
+    """serve_result_bytes_total{query} is the bytes of every query
+    batch's padded logits: lanes x 1 x token bucket x vocab x itemsize."""
+    from repro.serve import ServeEngine
+    eng = ServeEngine(tiny_params, tiny_cfg, n_slots=6, cache_len=64,
+                      batch_buckets=(1, 2, 4), token_buckets=(4, 8, 16))
+    shapes = []
+    note = eng._note_shape
+
+    def record(op, lanes, token_len, masked):
+        shapes.append((op, lanes, token_len))
+        note(op, lanes, token_len, masked)
+    eng._note_shape = record
+    verdicts = []
+    for s, n in enumerate((3, 5, 9, 2, 16)):
+        eng.create_session(f"u{s}")
+        verdicts.append(eng.query(f"u{s}", np.arange(n, dtype=np.int32)))
+    eng.run()
+    itemsize = verdicts[0].request.result.dtype.itemsize
+    want = sum(lanes * 1 * t * tiny_cfg.vocab_size * itemsize
+               for op, lanes, t in shapes if op == "query")
+    fam = eng.obs.registry.get("serve_result_bytes_total")
+    assert len([sh for sh in shapes if sh[0] == "query"]) > 1
+    assert fam.labels(kind="query").value == want
+    assert fam.labels(kind="ingest").value == 0
+
+
+def test_profiler_trace_holds_engine_spans(tiny_cfg, tiny_params,
+                                           tmp_path):
+    """A CPU profiler trace of one traced drain holds every engine span,
+    each inside serve.drain."""
+    import glob
+
+    from repro.serve import ServeEngine
+    from repro.serve.engine import HOST_SPANS
+    eng = ServeEngine(tiny_params, tiny_cfg, n_slots=3, cache_len=32,
+                      batch_buckets=(1, 2, 4),
+                      obs=Observability.tracing())
+    for s in range(2):
+        eng.create_session(f"u{s}")
+        eng.ingest(f"u{s}", np.arange(5, dtype=np.int32))
+        eng.query(f"u{s}", np.arange(4, dtype=np.int32))
+    eng.run()                                   # compile outside the trace
+    for s in range(2):
+        eng.ingest(f"u{s}", np.arange(5, dtype=np.int32))
+        eng.query(f"u{s}", np.arange(4, dtype=np.int32))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.run()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    spans = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+             for plane in pd.planes for line in plane.lines
+             for ev in line.events if ev.name.startswith("serve.")]
+    assert {n for n, _, _ in spans} == set(HOST_SPANS)
+    (drain,) = [(a, b) for n, a, b in spans if n == "serve.drain"]
+    for name, a, b in spans:
+        assert drain[0] <= a <= b <= drain[1], name
+    # one fetch and one deliver per query batch, after the dispatches
+    fetch = [a for n, a, _ in spans if n == "serve.fetch"]
+    dispatch = [b for n, _, b in spans if n == "serve.dispatch"]
+    assert len(fetch) == 1 and len(dispatch) == 2
+    assert min(fetch) >= max(dispatch)
+
+
 # -- timer lint --------------------------------------------------------
 
 def test_no_stray_timers_lint(tmp_path):
