@@ -10,7 +10,9 @@ per kind, per-tenant batch lanes <= the tenant's resident quota).
 (batched LRU restore/offload via `SessionManager`, tenant-quota-aware),
 then one fused jitted program per batch (`launch.serve.make_arena_step`)
 gathers their arena rows, runs the vmapped op, and scatters the updated
-rows back, fulfilling the requests.  After every popped batch the
+rows back, fulfilling the requests; a query or stream batch's logits
+are compacted to their real rows on the device before the host copy
+(`ServeEngine._compact`).  After every popped batch the
 backpressure backlog is pumped, so blocked submits drain as soon as
 queue capacity frees.
 
@@ -86,6 +88,52 @@ HOST_SPANS = ("serve.drain", "serve.pop", "serve.activate", "serve.pack",
               "serve.sync")
 _STAT_KEYS = ("requests", "tokens", "pad_lanes", "pad_tokens", "lanes",
               "batches")
+
+
+def result_rung(n_real: int, floor: int, padded: int) -> Optional[int]:
+    """Rows a batch's compacted result is fetched at: the smallest power
+    of two >= ``floor`` and >= ``n_real`` (the batch's real logit rows),
+    or None when that is more than half of the ``padded`` rows — the
+    gather would then cost about what it saves, and the padded result
+    is fetched whole."""
+    r = 1 << (max(n_real, floor, 1) - 1).bit_length()
+    return r if 2 * r <= padded else None
+
+
+def result_rungs(floor: int, padded: int) -> List[int]:
+    """Every rung `result_rung` can pick for one (lanes, token bucket)
+    shape, smallest first."""
+    out = []
+    r = result_rung(1, floor, padded)
+    while r is not None and 2 * r <= padded:
+        out.append(r)
+        r *= 2
+    return out
+
+
+def _row_gather(out, rows: int):
+    """AOT-compiled ``(result (B, 1, T, V), flat row indices (rows,)) ->
+    (rows, V)`` copy of the chosen rows, on the result's device.  A loop
+    of one-row slices: XLA's TPU gather of rows this wide first splits
+    the whole operand into column slices, a temporary about the size of
+    the padded result.  Called as compiled, never through the jit
+    wrapper, which keeps a cache of its own and would compile (or hit
+    the persistent cache) again at its first call."""
+    V = out.shape[-1]
+    dev = jax.sharding.SingleDeviceSharding(next(iter(out.devices())))
+
+    def gather(res, idx):
+        flat = res.reshape(-1, V)
+
+        def row(j, acc):
+            return jax.lax.dynamic_update_slice(
+                acc, jax.lax.dynamic_slice(flat, (idx[j], 0), (1, V)),
+                (j, 0))
+        return jax.lax.fori_loop(0, rows, row,
+                                 jnp.empty((rows, V), res.dtype), unroll=8)
+    return jax.jit(gather).lower(
+        jax.ShapeDtypeStruct(out.shape, out.dtype, sharding=out.sharding),
+        jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=dev)).compile()
 
 
 class ServeEngine:
@@ -353,7 +401,10 @@ class ServeEngine:
         self._shard: Dict[str, int] = {}  # sid -> owning arena shard
         self._tenant: Dict[str, str] = {}  # sid -> tenant
         self._cached: Dict[str, int] = {}  # sid -> KV-cache tokens used
-        self._undelivered = []         # [(requests, device out)] per batch
+        # [(requests, device result, row offsets or None)] per batch
+        self._undelivered = []
+        self._gathers = {}             # (lanes, tokens, rows) -> Compiled
+        self._gathered_shapes = set()  # (lanes, tokens) compiled for
 
     def _build_metrics(self) -> None:
         reg = self.obs.registry
@@ -381,8 +432,14 @@ class ServeEngine:
                 labels=("kind",)),
             "result_bytes": reg.counter(
                 "serve_result_bytes_total",
-                "bytes of the result arrays copied to the host, pad "
-                "lanes and pad positions included, per op kind",
+                "bytes of the result arrays actually copied to the "
+                "host (a compacted batch's rung rows, else the padded "
+                "result), per op kind",
+                labels=("kind",)),
+            "compactions": reg.counter(
+                "serve_result_compactions_total",
+                "batches whose result was compacted to its real logit "
+                "rows on the device before the host copy, per op kind",
                 labels=("kind",)),
             "wall_s": reg.counter(
                 "serve_wall_seconds_total",
@@ -395,7 +452,7 @@ class ServeEngine:
         }
         # pre-create per-kind children so exports carry explicit zeros
         for fam in ("requests", "tokens", "pad_lanes", "pad_tokens",
-                    "lanes", "batches", "result_bytes"):
+                    "lanes", "batches", "result_bytes", "compactions"):
             for k in _OP_STATE:
                 self._m[fam].labels(kind=k)
         for name in HOST_SPANS:
@@ -1021,6 +1078,40 @@ class ServeEngine:
             "prefix", f"cached sid={r.sid} slot={ent.slot} "
                       f"shard={ent.shard} groups={ent.mem_groups}")
 
+    def _compact(self, batch: ScheduledBatch, out):
+        """Gather a batch's real logit rows on the device, so that the
+        host copies them and not the bucket padding.  Returns (result,
+        row offsets), or (out, None) where the padded result is fetched
+        whole: a host-side result (a ``step_factory`` returning numpy),
+        or a batch whose rung
+        (`result_rung`) is over half its padded rows.  The first result
+        of each (lanes, token bucket) shape compiles the gather of every
+        rung at once, so that later drains compile nothing."""
+        if not isinstance(out, jax.Array):
+            return out, None
+        B, _, T, _ = out.shape
+        floor = min(self._token_buckets) if self.ragged else T
+        if (B, T) not in self._gathered_shapes:
+            self._gathered_shapes.add((B, T))
+            for r in result_rungs(floor, B * T):
+                self._gathers[(B, T, r)] = _row_gather(out, r)
+        n_real = sum(batch.valid_lens)
+        R = result_rung(n_real, floor, B * T) if n_real else None
+        if R is None:
+            return out, None
+        if (B, T, R) not in self._gathers:     # a refit lowered the floor
+            self._gathers[(B, T, R)] = _row_gather(out, R)
+        rows = np.empty(R, np.int32)
+        offsets = []
+        k = 0
+        for i, n in enumerate(batch.valid_lens):
+            offsets.append(k)
+            rows[k:k + n] = np.arange(i * T, i * T + n)
+            k += n
+        rows[k:] = rows[k - 1]
+        self._m["compactions"].labels(kind=batch.kind).inc()
+        return self._gathers[(B, T, R)](out, rows), offsets
+
     def _run_batch(self, batch: ScheduledBatch) -> None:
         mgr = self._mgr[_OP_STATE[batch.kind]]
         arena = mgr.arena
@@ -1053,12 +1144,13 @@ class ServeEngine:
                              masked)
             out, arena.slabs = step(self.params, arena.slabs, lane_ids,
                                     toks, lengths)
+            out, offsets = self._compact(batch, out)
         arena.mark_dirty(ids)
         # results are NOT materialized here — np.asarray(out) would
         # block on this batch's compute and serialize the drain; run()
         # converts all outs after the last dispatch (one transfer per
         # batch, per-request results become zero-copy numpy views)
-        self._undelivered.append((batch.requests, out))
+        self._undelivered.append((batch.requests, out, offsets))
         shape = f"{batch.bucket}x{batch.token_len}" \
             + ("/masked" if masked else "")
         for r in batch.requests:
@@ -1151,7 +1243,7 @@ class ServeEngine:
         arena.mark_dirty(gids)
         for s, sub in enumerate(sb.shards):
             if sub.requests:
-                self._undelivered.append((sub.requests, outs[s]))
+                self._undelivered.append((sub.requests, outs[s], None))
         shape = f"{S}x{B}x{L}" + ("/masked" if masked else "")
         for r in all_reqs:
             sess = mgr.sessions[r.sid]
@@ -1259,7 +1351,7 @@ class ServeEngine:
                 self.refit_token_buckets()
         if n:
             now = self.obs.clock.now()
-            for reqs, out in self._undelivered:
+            for reqs, out, offsets in self._undelivered:
                 out_np = None
                 if out is not None:
                     # the bytes follow from the shape: no sync here
@@ -1273,9 +1365,15 @@ class ServeEngine:
                         # slice off bucket padding: a request padded
                         # into a larger token lane only owns its first
                         # valid_len logit rows (the rest are masked-lane
-                        # garbage)
-                        r.result = out_np[i, 0, :r.token_len] \
-                            if out_np is not None else None
+                        # garbage); a compacted result holds just those
+                        # rows, request after request
+                        if out_np is None:
+                            r.result = None
+                        elif offsets is None:
+                            r.result = out_np[i, 0, :r.token_len]
+                        else:
+                            o = offsets[i]
+                            r.result = out_np[o:o + r.token_len]
                         r.done = True
                         if r.deadline is not None:
                             if now > r.deadline:

@@ -595,31 +595,73 @@ def test_host_spans_hold_the_time_advanced_inside(
     assert drain == sum(advanced.values())
 
 
-def test_result_bytes_count_every_fetched_logit(tiny_cfg, tiny_params):
-    """serve_result_bytes_total{query} is the bytes of every query
-    batch's padded logits: lanes x 1 x token bucket x vocab x itemsize."""
+def _fetched_bytes_run(tiny_cfg, tiny_params, lens):
+    """Serve one query per length; returns the engine, each query batch's
+    (lanes, token bucket, real rows) and the results' itemsize."""
     from repro.serve import ServeEngine
     eng = ServeEngine(tiny_params, tiny_cfg, n_slots=6, cache_len=64,
                       batch_buckets=(1, 2, 4), token_buckets=(4, 8, 16))
-    shapes = []
-    note = eng._note_shape
+    batches = []
+    compact = eng._compact
 
-    def record(op, lanes, token_len, masked):
-        shapes.append((op, lanes, token_len))
-        note(op, lanes, token_len, masked)
-    eng._note_shape = record
+    def record(batch, out):
+        if batch.kind == "query":
+            batches.append((batch.bucket, batch.token_len,
+                            sum(batch.valid_lens)))
+        return compact(batch, out)
+    eng._compact = record
     verdicts = []
-    for s, n in enumerate((3, 5, 9, 2, 16)):
+    for s, n in enumerate(lens):
         eng.create_session(f"u{s}")
         verdicts.append(eng.query(f"u{s}", np.arange(n, dtype=np.int32)))
     eng.run()
-    itemsize = verdicts[0].request.result.dtype.itemsize
-    want = sum(lanes * 1 * t * tiny_cfg.vocab_size * itemsize
-               for op, lanes, t in shapes if op == "query")
+    return eng, batches, verdicts[0].request.result.dtype.itemsize
+
+
+def _rung(n_real, lanes, t):
+    """Rows fetched: the power of two >= max(n_real, 4) (the smallest
+    token bucket) where that is at most half of lanes x t, else None."""
+    r = 4
+    while r < n_real:
+        r *= 2
+    return r if 2 * r <= lanes * t else None
+
+
+def test_result_bytes_count_every_fetched_logit(tiny_cfg, tiny_params):
+    """serve_result_bytes_total{query} is the bytes of every query
+    batch's fetched logits: the rung's rows x vocab x itemsize for a
+    compacted batch, lanes x 1 x token bucket x vocab x itemsize for the
+    rest; serve_result_compactions_total{query} counts the batches that
+    qualified."""
+    eng, batches, itemsize = _fetched_bytes_run(
+        tiny_cfg, tiny_params, (3, 5, 9, 2, 16))
+    V = tiny_cfg.vocab_size
+    want = sum((_rung(n, b, t) or b * 1 * t) * V * itemsize
+               for b, t, n in batches)
     fam = eng.obs.registry.get("serve_result_bytes_total")
-    assert len([sh for sh in shapes if sh[0] == "query"]) > 1
+    assert len(batches) > 1
     assert fam.labels(kind="query").value == want
     assert fam.labels(kind="ingest").value == 0
+    comp = eng.obs.registry.get("serve_result_compactions_total")
+    assert comp.labels(kind="query").value == sum(
+        _rung(n, b, t) is not None for b, t, n in batches)
+
+
+def test_result_compactions_count_qualified_batches(tiny_cfg, tiny_params):
+    """Short queries in wide buckets compact: the counter counts those
+    batches, and the bytes are their rungs' rows."""
+    eng, batches, itemsize = _fetched_bytes_run(
+        tiny_cfg, tiny_params, (1, 2, 1, 3, 6, 5))
+    V = tiny_cfg.vocab_size
+    qualified = [(b, t, n) for b, t, n in batches
+                 if _rung(n, b, t) is not None]
+    assert qualified
+    comp = eng.obs.registry.get("serve_result_compactions_total")
+    assert comp.labels(kind="query").value == len(qualified)
+    assert comp.labels(kind="ingest").value == 0
+    fam = eng.obs.registry.get("serve_result_bytes_total")
+    assert fam.labels(kind="query").value == sum(
+        (_rung(n, b, t) or b * t) * V * itemsize for b, t, n in batches)
 
 
 def test_profiler_trace_holds_engine_spans(tiny_cfg, tiny_params,

@@ -831,3 +831,164 @@ def test_zero_batch_run_syncs_async_offload(tiny_cfg, params):
     assert len(mgr._inflight) == 1       # transfer in flight
     assert eng.run() == 0                # zero batches popped...
     assert len(mgr._inflight) == 0       # ...but the barrier still ran
+
+
+# ---------------------------------------------------------------------------
+# result delivery: device-side row compaction before the host copy
+# ---------------------------------------------------------------------------
+
+def _record_padded(eng):
+    """Wrap ``eng._compact`` to keep each batch's padded fused-step
+    result on the host, beside whether the engine compacted it."""
+    seen = []
+    compact = eng._compact
+
+    def record(batch, out):
+        padded = None if out is None else np.asarray(out)
+        res, offsets = compact(batch, out)
+        seen.append((list(batch.requests), padded, offsets is not None))
+        return res, offsets
+    eng._compact = record
+    return seen
+
+
+def _assert_results_are_padded_rows(seen):
+    for reqs, padded, _ in seen:
+        for i, r in enumerate(reqs):
+            want = padded[i, 0, :r.token_len]
+            assert r.result.shape == want.shape
+            assert r.result.dtype == want.dtype
+            np.testing.assert_array_equal(r.result.view(np.uint8),
+                                          want.view(np.uint8))
+
+
+@pytest.mark.parametrize("token_buckets,lens,compacted", [
+    ((4, 8, 16), (2, 3, 1), True),        # 3 real lanes + 1 pad lane
+    ((4, 16), (5,), True),                # one real lane, 5 of 16 rows
+    ((4, 8, 16), (4, 4, 4, 4), False),    # full batch: fetched whole
+    ((4, 8, 16), (3, 1), True),           # 4 rows of 8
+    ((4, 8, 16), (9, 16), False),         # 25 real of 32: rung 32
+    ((4, 8, 16), (9, 16, 2, 3), True),    # 30 real of 64 rows: rung 32
+])
+def test_query_results_bit_equal_padded_rows(tiny_cfg, params,
+                                             token_buckets, lens,
+                                             compacted):
+    """A query's result is bit for bit the fused step's own padded rows
+    ``out[i, 0, :L]``, in its shape and dtype, whether the batch was
+    compacted on the device or fetched whole."""
+    eng = ServeEngine(params, tiny_cfg, n_slots=4, cache_len=64,
+                      batch_buckets=(1, 2, 4), token_buckets=token_buckets)
+    seen = _record_padded(eng)
+    reqs = []
+    for s, n in enumerate(lens):
+        eng.create_session(f"u{s}")
+        reqs.append(eng.query(f"u{s}", np.asarray(_tokens(70 + s, n)))
+                    .request)
+    eng.run()
+    assert all(r.done and r.result.shape == (r.token_len, 128)
+               for r in reqs)
+    _assert_results_are_padded_rows(seen)
+    assert any(c for *_, c in seen) == compacted
+    fam = eng.obs.registry.get("serve_result_compactions_total")
+    assert fam.labels(kind="query").value == sum(c for *_, c in seen)
+
+
+def test_stream_results_bit_equal_padded_rows(tiny_cfg):
+    """Stream batches take the same compaction: results equal the padded
+    rows bit for bit, on a ragged batch with a pad lane."""
+    cfg = _stream_cfg(tiny_cfg)
+    p = T.init_lm(jax.random.PRNGKey(1), cfg)
+    eng = ServeEngine(p, cfg, n_slots=1, cache_len=8, stream_slots=4,
+                      batch_buckets=(1, 2, 4), token_buckets=(4, 8, 16))
+    seen = _record_padded(eng)
+    reqs = []
+    for s, n in enumerate((1, 2, 1)):
+        eng.create_session(f"st{s}", kind="stream")
+        reqs.append(eng.stream(f"st{s}", np.asarray(_tokens(80 + s, n)))
+                    .request)
+    eng.run()
+    assert [c for *_, c in seen] == [True]
+    assert [r.result.shape for r in reqs] == [(1, 128), (2, 128), (1, 128)]
+    _assert_results_are_padded_rows(seen)
+    fam = eng.obs.registry.get("serve_result_compactions_total")
+    assert fam.labels(kind="stream").value == 1
+    assert fam.labels(kind="query").value == 0
+
+
+@pytest.mark.parametrize("n_real,floor,padded,want", [
+    (1, 4, 16, 4), (4, 4, 16, 4), (5, 4, 16, 8), (8, 4, 16, 8),
+    (9, 4, 16, None),                     # rung 16 is all 16 rows
+    (8, 4, 8, None),
+    (4, 4, 8, 4),                         # exactly half: compacted
+    (5, 4, 8, None),
+    (65, 64, 256, 128), (129, 64, 256, None), (3, 64, 4096, 64),
+    (1024, 64, 4096, 1024), (1025, 64, 4096, 2048),
+    (2049, 64, 4096, None), (7, 3, 16, 8), (1, 3, 16, 4),
+])
+def test_result_rung_half_size_rule(n_real, floor, padded, want):
+    """The rung is the smallest power of two at least the floor and the
+    real rows, taken only while it is at most half the padded rows."""
+    from repro.serve.engine import result_rung
+    assert result_rung(n_real, floor, padded) == want
+
+
+@pytest.mark.parametrize("lens,rows", [
+    ((2, 2, 2, 2), 8),                    # 8 real of 16: rung 8, half
+    ((3, 2, 2, 2), None),                 # 9 real: rung 16, fetched whole
+])
+def test_result_bytes_at_half_size_boundary(tiny_cfg, params, lens, rows):
+    """At the R <= B*T/2 boundary the engine fetches the rung's rows, and
+    past it the padded result; the byte counter follows."""
+    eng = ServeEngine(params, tiny_cfg, n_slots=4, cache_len=64,
+                      batch_buckets=(1, 2, 4), token_buckets=(4, 8, 16))
+    for s, n in enumerate(lens):
+        eng.create_session(f"u{s}")
+        eng.query(f"u{s}", np.asarray(_tokens(90 + s, n)))
+    eng.run()
+    fetched = (rows if rows is not None else 4 * 4) * 128 * 4   # float32
+    reg = eng.obs.registry
+    assert reg.get("serve_result_bytes_total").labels(
+        kind="query").value == fetched
+    assert reg.get("serve_result_compactions_total").labels(
+        kind="query").value == (rows is not None)
+
+
+def test_no_compiles_after_every_fused_shape_seen(tiny_cfg, params):
+    """Once each fused query shape has run once, its row gathers exist
+    too: random query mixes afterwards compile nothing."""
+    import jax.monitoring as mon
+    buckets, tbuckets = (1, 2, 4), (4, 8, 16)
+    eng = ServeEngine(params, tiny_cfg, n_slots=4, cache_len=1024,
+                      batch_buckets=buckets, token_buckets=tbuckets)
+    sids = [f"u{s}" for s in range(4)]
+    for s in sids:
+        eng.create_session(s)
+    rng = np.random.default_rng(7)
+
+    def drain(lens):
+        for s, n in zip(sids, lens):
+            eng.query(s, rng.integers(0, 128, n, dtype=np.int32))
+        eng.run()
+    for b in buckets:
+        for t in tbuckets:
+            drain([t] * b)                      # unmasked
+            drain([t] + [t - 1] * (b - 1) if b > 1 else [t - 1])  # masked
+    compiles = []
+
+    def listen(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+    mon.register_event_duration_secs_listener(listen)
+    try:
+        before = eng.obs.registry.get("serve_result_compactions_total") \
+            .labels(kind="query").value
+        for _ in range(12):
+            t = int(rng.choice(tbuckets))
+            lo = {4: 1, 8: 5, 16: 9}[t]
+            drain(rng.integers(lo, t + 1, int(rng.integers(1, 5))).tolist())
+        after = eng.obs.registry.get("serve_result_compactions_total") \
+            .labels(kind="query").value
+    finally:
+        mon.unregister_event_duration_listener(listen)
+    assert compiles == []
+    assert after > before                       # the mixes did compact
